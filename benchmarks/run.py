@@ -38,6 +38,7 @@ MODULES = [
 def main() -> None:
     quick = "--full" not in sys.argv
     print("name,us_per_call,derived")
+    failed = []
     for mod_name, desc in MODULES:
         t0 = time.time()
         print(f"# {mod_name}: {desc}", flush=True)
@@ -45,8 +46,11 @@ def main() -> None:
             mod = __import__(f"benchmarks.{mod_name}", fromlist=["main"])
             mod.main(quick=quick)
         except Exception:
+            failed.append(mod_name)
             print(f"{mod_name}/ERROR,0.0,{traceback.format_exc(limit=3)!r}")
         print(f"# {mod_name} done in {time.time()-t0:.1f}s", flush=True)
+    if failed:
+        sys.exit(f"failed modules: {', '.join(failed)}")
 
 
 if __name__ == '__main__':

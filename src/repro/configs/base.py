@@ -2,8 +2,10 @@
 
 Every assigned architecture is a frozen `ArchConfig`; the four assigned
 input-shape cells are `ShapeConfig`s.  `reduced()` produces the small-config
-variant used by CPU smoke tests and the RL experiments; the full config is
-exercised via the 512-device dry-run (ShapeDtypeStruct only).
+variant used by CPU smoke tests and the RL experiments; `cut()` keeps every
+width and trims only depth / vocabulary rows, which is how a full config
+runs on one chip; the full config is exercised via the 512-device dry-run
+(ShapeDtypeStruct only).
 """
 from __future__ import annotations
 
@@ -165,6 +167,16 @@ class ArchConfig:
         return dense_total - inactive
 
     # ------------------------------------------------------------------
+    def cut(self, n_layers: Optional[int] = None,
+            vocab_size: Optional[int] = None) -> "ArchConfig":
+        """Every published width kept; only depth and (for training) the
+        vocabulary rows are cut — the way a full config is sized to one
+        chip.  None keeps the published value."""
+        return dataclasses.replace(
+            self,
+            n_layers=self.n_layers if n_layers is None else n_layers,
+            vocab_size=self.vocab_size if vocab_size is None else vocab_size)
+
     def reduced(self, **overrides) -> "ArchConfig":
         """Small same-family variant for CPU smoke tests / RL experiments."""
         changes = dict(

@@ -21,7 +21,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh
 
-from repro.distributed.compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 

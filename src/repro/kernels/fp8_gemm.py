@@ -20,6 +20,12 @@ Layout / grid:
   scale block; K is the innermost (minor) grid dim so the f32 accumulator
   tile stays resident in VMEM across the K loop.
 
+  Scale blocks follow the TPU tiling rule (the last two block dims divide
+  by (8, 128) or equal the array's): a step loads its rows' whole scale
+  row (BM, K/128) and the w-scales expanded to columns, (K/128, BN), and
+  picks K-block `k` out of each with an iota mask.  Both blocks keep
+  their index across the K loop, so each is fetched once per tile.
+
 VMEM budget at the default BM=256, BN=256, BK=128:
   A tile 256*128*1B = 32KiB, W tile 128*256*1B = 32KiB,
   acc 256*256*4B = 256KiB, scales < 2KiB  ->  « 16MiB VMEM; the MXU sees
@@ -57,8 +63,15 @@ def _fp8_gemm_kernel(a_ref, w_ref, a_s_ref, w_s_ref, out_ref, acc_ref, *,
     partial = jax.lax.dot_general(
         a, w, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
     )
-    a_s = a_s_ref[...]                         # (BM, 1) f32
-    w_s = jnp.repeat(w_s_ref[...], BK, axis=1)  # (1, BN/128)->(1, BN) f32
+    # select K-block k: one nonzero term per sum, so the pick is exact
+    a_s_all = a_s_ref[...]                                  # (BM, K/128)
+    w_s_all = w_s_ref[...]                                  # (K/128, BN)
+    a_s = jnp.sum(jnp.where(
+        jax.lax.broadcasted_iota(jnp.int32, a_s_all.shape, 1) == k,
+        a_s_all, 0.0), axis=1, keepdims=True)               # (BM, 1)
+    w_s = jnp.sum(jnp.where(
+        jax.lax.broadcasted_iota(jnp.int32, w_s_all.shape, 0) == k,
+        w_s_all, 0.0), axis=0, keepdims=True)               # (1, BN)
     acc_ref[...] += partial * (a_s * w_s)
 
     @pl.when(k == n_k - 1)
@@ -92,19 +105,19 @@ def fp8_gemm(
 
     grid = (m // bm, n // bn, n_k)
     kernel = functools.partial(_fp8_gemm_kernel, n_k=n_k, out_dtype=out_dtype)
+    # one w-scale per (K-block, 128-wide N-block), repeated per column
+    w_scales_cols = jnp.repeat(w_scales.astype(jnp.float32), BK, axis=1)
     return pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, BK), lambda i, j, kk: (i, kk)),
             pl.BlockSpec((BK, bn), lambda i, j, kk: (kk, j)),
-            pl.BlockSpec((bm, 1), lambda i, j, kk: (i, kk)),
-            # one w-scale per (K-block, 128-wide N-block): use the finest
-            # granularity (1, bn//128) so bn > 128 still maps correctly.
-            pl.BlockSpec((1, bn // BK), lambda i, j, kk: (kk, j)),
+            pl.BlockSpec((bm, n_k), lambda i, j, kk: (i, 0)),
+            pl.BlockSpec((n_k, bn), lambda i, j, kk: (0, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
-    )(a, w, a_scales, w_scales)
+    )(a, w, a_scales.astype(jnp.float32), w_scales_cols)

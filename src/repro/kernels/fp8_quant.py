@@ -13,6 +13,12 @@ every rollout forward pass.
 Grid: one program per (BM, 128) slab; a program reduces its slab to scales
 and writes the quantized payload.  VMEM at BM=256: in 256*128*2B = 64KiB,
 out 32KiB — trivially resident.
+
+Scale outputs obey the TPU tiling rule (the last two block dims divide by
+(8, 128) or equal the array's): a program owns a whole scale row — (BM,
+K/128) for activations, (1, N/128) of the (K/128, 1, N/128) weight-scale
+array — which stays resident across the inner grid axis, and writes its
+own column with an iota select.
 """
 from __future__ import annotations
 
@@ -27,28 +33,38 @@ from repro.core.precision import E4M3, FP8_MAX, ScaleFormat
 _EPS = 1e-12
 
 
-def _quant_act_kernel(x_ref, q_ref, s_ref, *, fp8_max: float, fp8_dtype, pow2: bool):
+def _quantize_tile(x, q_ref, amax, *, fp8_max: float, fp8_dtype,
+                   pow2: bool):
+    """Scale from `amax`, write the fp8 payload of `x`, return the scale."""
+    scale = jnp.maximum(amax, _EPS) / fp8_max
+    if pow2:
+        scale = jnp.exp2(jnp.ceil(jnp.log2(scale)))
+    q = jnp.clip(x / scale, -fp8_max, fp8_max)
+    q_ref[...] = q.astype(fp8_dtype)
+    return scale
+
+
+def _put_column(s_ref, scale, j):
+    """Write `scale` into column j of the resident scale row block."""
+    col = jax.lax.broadcasted_iota(jnp.int32, s_ref.shape, s_ref.ndim - 1)
+    s_ref[...] = jnp.where(col == j, scale, s_ref[...])
+
+
+def _quant_act_kernel(x_ref, q_ref, s_ref, **kw):
     """1x128 tiles: one scale per (row, 128-col block)."""
     x = x_ref[...].astype(jnp.float32)               # (BM, 128)
     amax = jnp.max(jnp.abs(x), axis=1, keepdims=True)  # (BM, 1)
-    scale = jnp.maximum(amax, _EPS) / fp8_max
-    if pow2:
-        scale = jnp.exp2(jnp.ceil(jnp.log2(scale)))
-    q = jnp.clip(x / scale, -fp8_max, fp8_max)
-    q_ref[...] = q.astype(fp8_dtype)
-    s_ref[...] = scale
+    _put_column(s_ref, _quantize_tile(x, q_ref, amax, **kw),
+                pl.program_id(1))
 
 
-def _quant_weight_kernel(x_ref, q_ref, s_ref, *, fp8_max: float, fp8_dtype, pow2: bool):
+def _quant_weight_kernel(x_ref, q_ref, s_ref, **kw):
     """128x128 blocks: one scale per program."""
     x = x_ref[...].astype(jnp.float32)               # (128, 128)
-    amax = jnp.max(jnp.abs(x))
-    scale = jnp.maximum(amax, _EPS) / fp8_max
-    if pow2:
-        scale = jnp.exp2(jnp.ceil(jnp.log2(scale)))
-    q = jnp.clip(x / scale, -fp8_max, fp8_max)
-    q_ref[...] = q.astype(fp8_dtype)
-    s_ref[...] = scale[None, None]
+    amax = jnp.max(jnp.max(jnp.abs(x), axis=1, keepdims=True), axis=0,
+                   keepdims=True)                    # (1, 1)
+    _put_column(s_ref, _quantize_tile(x, q_ref, amax, **kw)[None],
+                pl.program_id(1))
 
 
 @functools.partial(jax.jit, static_argnames=("fp8_dtype", "scale_format", "bm", "interpret"))
@@ -76,7 +92,7 @@ def quantize_activation_kernel(
         in_specs=[pl.BlockSpec((bm, 128), lambda i, j: (i, j))],
         out_specs=[
             pl.BlockSpec((bm, 128), lambda i, j: (i, j)),
-            pl.BlockSpec((bm, 1), lambda i, j: (i, j)),
+            pl.BlockSpec((bm, k // 128), lambda i, j: (i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((m, k), fp8_dtype),
@@ -102,17 +118,18 @@ def quantize_weight_kernel(
         fp8_dtype=fp8_dtype,
         pow2=scale_format == ScaleFormat.UE8M0,
     )
-    return pl.pallas_call(
+    q, s = pl.pallas_call(
         kernel,
         grid=(k // 128, n // 128),
         in_specs=[pl.BlockSpec((128, 128), lambda i, j: (i, j))],
         out_specs=[
             pl.BlockSpec((128, 128), lambda i, j: (i, j)),
-            pl.BlockSpec((1, 1), lambda i, j: (i, j)),
+            pl.BlockSpec((1, 1, n // 128), lambda i, j: (i, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((k, n), fp8_dtype),
-            jax.ShapeDtypeStruct((k // 128, n // 128), jnp.float32),
+            jax.ShapeDtypeStruct((k // 128, 1, n // 128), jnp.float32),
         ],
         interpret=interpret,
     )(w)
+    return q, s.reshape(k // 128, n // 128)

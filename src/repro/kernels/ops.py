@@ -45,16 +45,15 @@ def quantize_activation(x: jax.Array, fp8_dtype=E4M3,
     k = shape[-1]
     x2 = x.reshape(-1, k)
     m = x2.shape[0]
-    x2 = _pad_to(x2, (1, 128))
-    # pick a row block that divides M
-    bm = 256
-    while m % bm and bm > 1:
-        bm //= 2
+    # row block: a multiple of the fp8 tile's 32 sublanes, capped at 256;
+    # padded rows are zeros and are sliced off with their scales
+    bm = min(256, -(-m // 32) * 32)
+    x2 = _pad_to(x2, (bm, 128))
     q, s = _quant.quantize_activation_kernel(
         x2, fp8_dtype=fp8_dtype, scale_format=scale_format, bm=bm,
         interpret=_interpret())
-    q = q[:, :k].reshape(shape)
-    s = s.reshape(shape[:-1] + (-1,))
+    q = q[:m, :k].reshape(shape)
+    s = s[:m].reshape(shape[:-1] + (-1,))
     return QuantizedTensor(q, s, (1,) * (len(shape) - 1) + (128,))
 
 

@@ -1,6 +1,9 @@
 import os
 os.environ["XLA_FLAGS"] = os.environ.get(
     "XLA_FLAGS", "") + " --xla_force_host_platform_device_count=512"
+# the 512 devices are virtual host devices: this process and the children
+# it starts (which inherit the environment) never take an accelerator
+os.environ["JAX_PLATFORMS"] = "cpu"
 # ^ MUST precede every other import: jax locks the device count on first init.
 
 """Multi-pod dry-run driver (assignment: MULTI-POD DRY-RUN).

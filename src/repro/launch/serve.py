@@ -3,6 +3,12 @@
     PYTHONPATH=src python -m repro.launch.serve --arch qwen3-8b --reduced \
         --requests 16 --precision fp8 --prefill-chunk 8 --eviction lru
 
+At published widths on one chip, cut only the depth (`--layers`):
+
+    PYTHONPATH=src python -m repro.launch.serve --arch qwen3-8b --layers 4 \
+        --precision fp8 --kernel-config all --block-size 16 \
+        --prefill-chunk 16 --requests 8
+
 Every layer pattern in the zoo serves: hybrid/SSM archs
 (`--arch jamba-1.5-large-398b --reduced`, `--arch mamba2-780m --reduced`)
 swap their recurrent state to host on preemption, and enc-dec archs
@@ -14,12 +20,14 @@ from __future__ import annotations
 
 import argparse
 import json
+import sys
 
 import jax
 import numpy as np
 
 from repro.configs import get_config
 from repro.data import tasks
+from repro.launch.runtime import describe_cut, enable_compile_cache
 from repro.launch.train import PRECISIONS
 from repro.obs import JsonlSink, StepTracer, chrome_trace
 from repro.models import init_params
@@ -42,6 +50,9 @@ def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-8b")
     ap.add_argument("--reduced", action="store_true")
+    ap.add_argument("--layers", type=int, default=None,
+                    help="cut the depth to N layers, every width kept "
+                         "(with or without --reduced)")
     ap.add_argument("--precision", choices=sorted(PRECISIONS), default="fp8")
     ap.add_argument("--requests", type=int, default=16)
     ap.add_argument("--max-new", type=int, default=12)
@@ -149,9 +160,11 @@ def main(argv=None):
         ap.error("fault injection needs --replicas >= 2: a single-replica "
                  "fleet has nowhere to fail work over to")
 
-    cfg = get_config(args.arch)
-    if args.reduced:
-        cfg = cfg.reduced(vocab_size=tasks.VOCAB_SIZE)
+    enable_compile_cache()
+    full = get_config(args.arch)
+    cfg = full.reduced(vocab_size=tasks.VOCAB_SIZE) if args.reduced else full
+    cfg = cfg.cut(n_layers=args.layers)
+    print(describe_cut(full, cfg), file=sys.stderr, flush=True)
     precision = PRECISIONS[args.precision]
     params = init_params(cfg, jax.random.key(args.seed))
     rollout_params, sync_stats = sync_policy_weights(params, precision)
@@ -193,6 +206,21 @@ def main(argv=None):
             args.chaos_seed, replicas=args.replicas, max_step=4,
             down_steps=args.crash_down_steps))
 
+    rng = np.random.default_rng(args.seed)
+    requests = []             # (prompt ids, enc-dec frames or None)
+    for i in range(args.requests):
+        prob = tasks.sample_problem(rng)
+        frames = None
+        if cfg.is_encdec:
+            # synthetic frame embeddings stand in for the audio frontend
+            n = int(rng.integers(min(3, args.src_pad), args.src_pad + 1))
+            frames = tasks.random_frames(args.seed * 1000 + i, n,
+                                         cfg.d_model)
+        requests.append((prob.prompt_ids, frames))
+    # room for the longest prompt, its new tokens and a verify's drafts
+    max_seq_len = max(len(ids) for ids, _ in requests) + args.max_new \
+        + (args.spec_k or 0)
+
     def mk_engine(i: int) -> ServingEngine:
         tracer = None
         if tracing:
@@ -200,7 +228,7 @@ def main(argv=None):
             tracers.append(tracer)
         return ServingEngine(rollout_params, cfg, precision,
                              tracer=tracer, faults=faults,
-                             max_slots=args.slots, max_seq_len=64,
+                             max_slots=args.slots, max_seq_len=max_seq_len,
                              kv_budget_bytes=budget, seed=args.seed + i,
                              block_size=args.block_size,
                              admission=args.admission,
@@ -215,18 +243,8 @@ def main(argv=None):
                              if args.spec_k else None)
 
     def submit_all(target):
-        rng = np.random.default_rng(args.seed)
-        for i in range(args.requests):
-            prob = tasks.sample_problem(rng)
-            frames = None
-            if cfg.is_encdec:
-                # synthetic frame embeddings stand in for the audio frontend
-                n = int(rng.integers(min(3, args.src_pad),
-                                     args.src_pad + 1))
-                frames = tasks.random_frames(args.seed * 1000 + i, n,
-                                             cfg.d_model)
-            target.submit(prob.prompt_ids, max_new=args.max_new, rid=i,
-                          frames=frames)
+        for i, (ids, frames) in enumerate(requests):
+            target.submit(ids, max_new=args.max_new, rid=i, frames=frames)
 
     def write_traces():
         if not tracing:
@@ -295,7 +313,7 @@ def main(argv=None):
         if report.latency is not None:
             out["latency"] = report.latency
         print(json.dumps(out, indent=2))
-        return
+        return out
 
     eng = mk_engine(0)
     submit_all(eng)
@@ -329,6 +347,7 @@ def main(argv=None):
     if report.latency is not None:
         out["latency"] = report.latency
     print(json.dumps(out, indent=2))
+    return out
 
 
 if __name__ == "__main__":

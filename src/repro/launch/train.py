@@ -3,14 +3,18 @@
     PYTHONPATH=src python -m repro.launch.train \
         --arch qwen3-8b --reduced --steps 50 --precision fp8 --tis
 
-On this CPU container you always want --reduced (full configs are exercised
-through the dry-run).  On a real pod the same entry point jits the trainer
-under the production mesh.
+On a CPU you want --reduced (full configs are exercised through the
+dry-run).  On one TPU chip, keep every published width and cut only the
+depth and the vocabulary rows (the task's ids are the leading rows):
+
+    PYTHONPATH=src python -m repro.launch.train --arch qwen3-8b \
+        --layers 4 --vocab-size 18992 --steps 3 --precision fp8 --tis
 """
 from __future__ import annotations
 
 import argparse
 import json
+import sys
 
 from repro.configs import get_config
 from repro.core.precision import (
@@ -22,6 +26,7 @@ from repro.core.precision import (
     RolloutCorrection,
 )
 from repro.data import tasks
+from repro.launch.runtime import describe_cut, enable_compile_cache
 from repro.obs import JsonlSink
 from repro.optim import AdamWConfig
 from repro.rl import RLConfig, RLTrainer
@@ -35,17 +40,29 @@ PRECISIONS = {
 }
 
 
-def build_trainer(args, metrics_sink=None) -> RLTrainer:
-    cfg = get_config(args.arch)
+def model_config(args):
+    """(published config, the config this run builds).  --reduced shrinks
+    every width for CPU runs; otherwise --layers / --vocab-size cut only
+    depth and vocabulary rows."""
+    full = get_config(args.arch)
     if args.reduced:
-        cfg = cfg.reduced(vocab_size=tasks.VOCAB_SIZE,
-                          n_layers=args.layers, d_model=args.d_model)
+        n_layers = 2 if args.layers is None else args.layers
+        return full, full.reduced(vocab_size=tasks.VOCAB_SIZE,
+                                  n_layers=n_layers, d_model=args.d_model)
+    cfg = full.cut(n_layers=args.layers, vocab_size=args.vocab_size)
+    if cfg.vocab_size < tasks.VOCAB_SIZE:
+        raise ValueError(f"--vocab-size {cfg.vocab_size} drops task ids "
+                         f"(needs >= {tasks.VOCAB_SIZE})")
+    return full, cfg
+
+
+def rl_config(args) -> RLConfig:
     precision = PRECISIONS[args.precision]
     correction = RolloutCorrection.TIS if args.tis else (
         RolloutCorrection.MIS if args.mis else RolloutCorrection.NONE)
     precision = precision.replace(correction=correction,
                                   rollout_router_replay=args.rrr)
-    rl = RLConfig(
+    return RLConfig(
         precision=precision,
         prompt_batch=args.prompt_batch,
         n_per_prompt=args.n_per_prompt,
@@ -56,15 +73,25 @@ def build_trainer(args, metrics_sink=None) -> RLTrainer:
         ckpt_every=args.ckpt_every,
         seed=args.seed,
     )
-    return RLTrainer(cfg, rl, metrics_sink=metrics_sink)
 
 
-def main(argv=None):
+def build_trainer(args, metrics_sink=None) -> RLTrainer:
+    return RLTrainer(model_config(args)[1], rl_config(args),
+                     metrics_sink=metrics_sink)
+
+
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-8b")
     ap.add_argument("--reduced", action="store_true")
-    ap.add_argument("--layers", type=int, default=2)
-    ap.add_argument("--d-model", type=int, default=128)
+    ap.add_argument("--layers", type=int, default=None,
+                    help="cut the depth to N layers (default: 2 with "
+                         "--reduced, else the published depth)")
+    ap.add_argument("--d-model", type=int, default=128,
+                    help="model width with --reduced")
+    ap.add_argument("--vocab-size", type=int, default=None,
+                    help="keep the leading N vocabulary rows (without "
+                         "--reduced; must hold the task's ids)")
     ap.add_argument("--steps", type=int, default=50)
     ap.add_argument("--precision", choices=sorted(PRECISIONS), default="fp8")
     ap.add_argument("--tis", action="store_true", default=True)
@@ -80,7 +107,9 @@ def main(argv=None):
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--ckpt-every", type=int, default=25)
-    ap.add_argument("--eval-every", type=int, default=25)
+    ap.add_argument("--eval-every", type=int, default=25,
+                    help="greedy eval at step 1 and every N steps "
+                         "(0 = never)")
     ap.add_argument("--resume", action="store_true")
     ap.add_argument("--metrics-out", default=None, metavar="PATH",
                     help="stream per-step metrics as JSONL (one step per "
@@ -92,8 +121,13 @@ def main(argv=None):
                          "serving side (repro.launch.serve --run-id) with "
                          "the SAME id to join trainer steps to the serving "
                          "steps that produced their rollout batches")
-    args = ap.parse_args(argv)
+    return ap
 
+
+def main(argv=None):
+    args = build_parser().parse_args(argv)
+    enable_compile_cache()
+    print(describe_cut(*model_config(args)), file=sys.stderr, flush=True)
     sink = JsonlSink(args.metrics_out, run_id=args.run_id) \
         if args.metrics_out else None
     trainer = build_trainer(args, metrics_sink=sink)
@@ -105,7 +139,8 @@ def main(argv=None):
         for _ in range(args.steps):
             m = trainer.train_step()
             history.append(m)
-            if m["step"] % args.eval_every == 0 or m["step"] == 1:
+            if args.eval_every and (m["step"] % args.eval_every == 0
+                                    or m["step"] == 1):
                 m["eval_accuracy"] = trainer.evaluate(n_problems=32)
             print(json.dumps({k: round(v, 5) if isinstance(v, float) else v
                               for k, v in m.items()}), flush=True)

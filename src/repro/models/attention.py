@@ -489,7 +489,7 @@ def attention_prefill_chunk(
         from repro.kernels import ops
         g = cfg.n_heads // kvh
         out = ops.fp8_paged_prefill_attention(
-            q.reshape(b, c, kvh, g, dh).astype(jnp.bfloat16),
+            q.reshape(b, c, kvh, g, dh),
             cache.k, cache.v, cache.k_scale, cache.v_scale,
             phys, start, lengths,
         ).reshape(b, c, cfg.n_heads * dh).astype(x.dtype)
@@ -547,9 +547,8 @@ def attention_decode(
     if use_kernel:
         from repro.kernels import ops
         g = h // kvh
-        qk = q.reshape(b, kvh, g, dh) if g * kvh == h else q.reshape(b, kvh, g, dh)
         out = ops.fp8_decode_attention(
-            qk.reshape(b, kvh, g, dh).astype(jnp.bfloat16),
+            q.reshape(b, kvh, g, dh),
             cache.k, cache.v, cache.k_scale, cache.v_scale, new_lengths,
         ).reshape(b, 1, h * dh).astype(x.dtype)
     else:
@@ -600,7 +599,7 @@ def _paged_attention_over_table(
         from repro.kernels import ops
         g = h // kvh
         out = ops.fp8_paged_decode_attention(
-            q.reshape(b, kvh, g, dh).astype(jnp.bfloat16),
+            q.reshape(b, kvh, g, dh),
             cache.k, cache.v, cache.k_scale, cache.v_scale, phys,
             new_lengths,
         ).reshape(b, 1, h * dh).astype(x.dtype)

@@ -80,9 +80,54 @@ class RLConfig:
         return self.prompt_batch * self.n_per_prompt
 
 
+def build_update_fn(cfg, rl: RLConfig):
+    """The jitted policy update `(params, opt_state, batch) -> (params,
+    opt_state, stats)`: token-level DAPO loss with the rollout correction,
+    then AdamW.  The old params and optimizer state die with the update,
+    so both are donated — the step holds one copy of each, not two."""
+    versioned = rl.rollout_backend == "fleet"
+
+    def update_fn(params, opt_state, batch):
+        def loss_fn(p):
+            logits_inputs = {"tokens": batch["packed_tokens"]}
+            logp_all, aux = _score_logprobs(p, logits_inputs, cfg)
+            resp_logps = _gather(logp_all, batch)
+            loss, stats = dapo_token_loss(
+                logp_theta=resp_logps,
+                logp_old=jax.lax.stop_gradient(resp_logps),
+                logp_rollout=batch["rollout_logps"],
+                advantages=batch["advantages"],
+                mask=batch["mask"],
+                precision=rl.precision,
+                cfg=rl.loss,
+                metrics_mask=batch["response_mask"],
+                token_versions=(batch["token_versions"]
+                                if versioned else None),
+                num_versions=_VERSION_SLOTS if versioned else 1,
+            )
+            if aux.get("moe"):
+                aux_losses = [v["aux_loss"].mean()
+                              for v in aux["moe"].values()]
+                loss = loss + rl.moe_aux_coef * sum(aux_losses)
+                stats["moe_aux_loss"] = sum(aux_losses)
+            return loss, stats
+
+        (loss, stats), grads = jax.value_and_grad(
+            loss_fn, has_aux=True)(params)
+        params, opt_state, opt_stats = opt_update(
+            params, grads, opt_state, rl.optimizer)
+        stats.update(opt_stats)
+        stats["loss"] = loss
+        return params, opt_state, stats
+
+    return jax.jit(update_fn, donate_argnums=(0, 1))
+
+
 class RLTrainer:
     def __init__(self, cfg, rl: RLConfig, params=None, metrics_sink=None):
-        """cfg: a *reduced* ArchConfig (decoder-only family).
+        """cfg: a decoder-only ArchConfig, `reduced()` for CPU runs or
+        `cut()` to one chip.  `params`, when given, are donated to the
+        first update: the caller's arrays are deleted by it.
 
         metrics_sink: optional object with a ``write(dict)`` method (e.g.
         `repro.obs.JsonlSink`); every `train_step()` streams its metrics
@@ -108,52 +153,13 @@ class RLTrainer:
         if rl.rollout_backend == "fleet":
             self.syncer = WeightSyncer(self._rollout_precision())
             self._fleet = None           # built at the first weight push
-        self._update_fn = self._build_update()
+        self._update_fn = build_update_fn(cfg, rl)
 
     # ------------------------------------------------------------------
     def _rollout_precision(self) -> PrecisionConfig:
         if self.rl.calibration == "trainer":
             return calib_mod.trainer_side_precision(self.rl.precision)
         return self.rl.precision
-
-    def _build_update(self):
-        cfg, rl = self.cfg, self.rl
-        versioned = rl.rollout_backend == "fleet"
-
-        def update_fn(params, opt_state, batch):
-            def loss_fn(p):
-                logits_inputs = {"tokens": batch["packed_tokens"]}
-                logp_all, aux = _score_logprobs(p, logits_inputs, cfg)
-                resp_logps = _gather(logp_all, batch)
-                loss, stats = dapo_token_loss(
-                    logp_theta=resp_logps,
-                    logp_old=jax.lax.stop_gradient(resp_logps),
-                    logp_rollout=batch["rollout_logps"],
-                    advantages=batch["advantages"],
-                    mask=batch["mask"],
-                    precision=rl.precision,
-                    cfg=rl.loss,
-                    metrics_mask=batch["response_mask"],
-                    token_versions=(batch["token_versions"]
-                                    if versioned else None),
-                    num_versions=_VERSION_SLOTS if versioned else 1,
-                )
-                if aux.get("moe"):
-                    aux_losses = [v["aux_loss"].mean()
-                                  for v in aux["moe"].values()]
-                    loss = loss + rl.moe_aux_coef * sum(aux_losses)
-                    stats["moe_aux_loss"] = sum(aux_losses)
-                return loss, stats
-
-            (loss, stats), grads = jax.value_and_grad(
-                loss_fn, has_aux=True)(params)
-            params, opt_state, opt_stats = opt_update(
-                params, grads, opt_state, rl.optimizer)
-            stats.update(opt_stats)
-            stats["loss"] = loss
-            return params, opt_state, stats
-
-        return jax.jit(update_fn)
 
     # ------------------------------------------------------------------
     # fleet rollout backend
@@ -280,7 +286,8 @@ class RLTrainer:
                 num_samples_per_prompt=rl.n_per_prompt,
                 shared_prefix_blocks=int(np.min(batch.lengths)) // page_size,
             )
-            traj = jax.tree.map(lambda x: x, traj)  # materialize
+            jax.block_until_ready(traj)
+            del rollout_params        # free the fp8 copy before the update
         rollout_s = time.perf_counter() - t_roll
         gen_tokens = float(traj.response_mask.sum())
 
@@ -308,8 +315,10 @@ class RLTrainer:
         }
         if token_versions is not None:
             update_batch["token_versions"] = token_versions
-        self.params, self.opt_state, stats = self._update_fn(
-            self.params, self.opt_state, update_batch)
+        t_upd = time.perf_counter()
+        self.params, self.opt_state, stats = jax.block_until_ready(
+            self._update_fn(self.params, self.opt_state, update_batch))
+        update_s = time.perf_counter() - t_upd
 
         # 6. trainer-side calibration for the *next* rollout (paper §B.2)
         if rl.calibration == "trainer" and not cfg.attention_free:
@@ -338,6 +347,7 @@ class RLTrainer:
             response_len_mean=float(rlen.mean()),
             rollout_s=rollout_s,
             rollout_tokens_per_s=gen_tokens / max(rollout_s, 1e-9),
+            update_s=update_s,
             step_s=time.perf_counter() - t_start,
             sync_ms=sync_stats.get("sync_ms", 0.0),
         )

@@ -29,6 +29,10 @@ from repro.core.precision import PrecisionConfig
 from repro.core.quant import QuantizedTensor, quantization_rel_error
 
 
+# one jit for every sync: traced once per (param shapes, precision)
+_quantize_params = jax.jit(quantize_params, static_argnums=1)
+
+
 def sync_policy_weights(
     train_params,
     precision: PrecisionConfig,
@@ -41,11 +45,10 @@ def sync_policy_weights(
             precision.router_dtype.value == "bf16":
         return train_params, {"sync_ms": 0.0, "quantized_leaves": 0}
 
-    quant_fn = jax.jit(lambda p: quantize_params(p, precision))
-    rollout_params = quant_fn(train_params)
+    rollout_params = _quantize_params(train_params, precision)
     if rollout_shardings is not None:
         rollout_params = jax.device_put(rollout_params, rollout_shardings)
-    jax.block_until_ready(jax.tree.leaves(rollout_params)[0])
+    jax.block_until_ready(rollout_params)
     stats = dict(count_quantized(rollout_params))
     stats["sync_ms"] = (time.perf_counter() - t0) * 1e3
     return rollout_params, stats

@@ -61,8 +61,9 @@ def test_sharded_train_step_compiles_and_reduces():
         from repro.configs import get_config
         from repro.models import init_params, forward_train
         from repro.distributed import ShardingRules
+        from repro.launch.mesh import make_test_mesh
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_test_mesh(dp=2, tp=4)
         cfg = get_config("granite-moe-3b-a800m").reduced(
             d_model=64, d_ff=64, vocab_size=256, n_layers=2)
         params = init_params(cfg, jax.random.key(0))
@@ -114,6 +115,7 @@ def test_sharded_matches_single_device():
         from repro.configs import get_config
         from repro.models import init_params, forward_train
         from repro.distributed import ShardingRules
+        from repro.launch.mesh import make_test_mesh
 
         cfg = get_config("llama3.2-3b").reduced(
             d_model=64, d_ff=128, vocab_size=256, n_layers=2, n_heads=4,
@@ -128,7 +130,7 @@ def test_sharded_matches_single_device():
 
         ref = float(jax.jit(loss_fn)(params, tokens))
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_test_mesh(dp=2, tp=4)
         rules = ShardingRules(mesh, zero3=True)
         pspec = rules.params(params)
         tok_sh = NamedSharding(mesh, P("data", None))
@@ -185,7 +187,7 @@ def test_compressed_psum_close_to_exact():
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
         from repro.distributed import compressed_psum
-        from repro.distributed.compat import shard_map
+        from jax import shard_map
         from repro.distributed.compression import comm_bytes
 
         mesh = jax.make_mesh((8,), ("data",))

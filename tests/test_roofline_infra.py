@@ -50,7 +50,7 @@ def test_collective_bytes_on_real_compile():
 
     from jax.sharding import PartitionSpec as P
 
-    from repro.distributed.compat import shard_map
+    from jax import shard_map
 
     mesh = jax.make_mesh((1,), ("i",))
     g = shard_map(f, mesh=mesh, in_specs=P(), out_specs=P(), check_vma=False)

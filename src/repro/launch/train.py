@@ -107,9 +107,7 @@ def build_parser() -> argparse.ArgumentParser:
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--ckpt-every", type=int, default=25)
-    ap.add_argument("--eval-every", type=int, default=25,
-                    help="greedy eval at step 1 and every N steps "
-                         "(0 = never)")
+    ap.add_argument("--eval-every", type=int, default=25)
     ap.add_argument("--resume", action="store_true")
     ap.add_argument("--metrics-out", default=None, metavar="PATH",
                     help="stream per-step metrics as JSONL (one step per "
@@ -139,8 +137,7 @@ def main(argv=None):
         for _ in range(args.steps):
             m = trainer.train_step()
             history.append(m)
-            if args.eval_every and (m["step"] % args.eval_every == 0
-                                    or m["step"] == 1):
+            if m["step"] % args.eval_every == 0 or m["step"] == 1:
                 m["eval_accuracy"] = trainer.evaluate(n_problems=32)
             print(json.dumps({k: round(v, 5) if isinstance(v, float) else v
                               for k, v in m.items()}), flush=True)

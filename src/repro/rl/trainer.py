@@ -153,7 +153,7 @@ class RLTrainer:
         if rl.rollout_backend == "fleet":
             self.syncer = WeightSyncer(self._rollout_precision())
             self._fleet = None           # built at the first weight push
-        self._update_fn = build_update_fn(cfg, rl)
+        self.update_fn = build_update_fn(cfg, rl)
 
     # ------------------------------------------------------------------
     def _rollout_precision(self) -> PrecisionConfig:
@@ -317,7 +317,7 @@ class RLTrainer:
             update_batch["token_versions"] = token_versions
         t_upd = time.perf_counter()
         self.params, self.opt_state, stats = jax.block_until_ready(
-            self._update_fn(self.params, self.opt_state, update_batch))
+            self.update_fn(self.params, self.opt_state, update_batch))
         update_s = time.perf_counter() - t_upd
 
         # 6. trainer-side calibration for the *next* rollout (paper §B.2)

@@ -1,0 +1,9 @@
+"""Share of the traced window in which no operation ran on the device:
+1 minus the union of the device's operation intervals over the window, in
+percent."""
+
+
+def read(run):
+    if run.kind != "rollout" or not run.trace:
+        return None
+    return 100.0 * (1.0 - run.trace["busy_s"] / run.trace["window_s"])

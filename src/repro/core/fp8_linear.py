@@ -33,14 +33,21 @@ from repro.core.quant import (
     quantize_activation,
     quantize_weight,
 )
+from repro.obs.scopes import scope
 
 
+@scope("matmul")
 def _dot(x: jax.Array, w: jax.Array) -> jax.Array:
     """bf16 x bf16 -> f32-accumulated matmul, output in x.dtype."""
     return jax.lax.dot_general(
         x, w, (((x.ndim - 1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
     ).astype(x.dtype)
+
+
+@scope("weight_dequant")
+def _weight(w_q: QuantizedTensor, dtype) -> jax.Array:
+    return dequantize(w_q, dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -63,10 +70,14 @@ def fp8_linear_rollout(
     if use_kernel:
         from repro.kernels import ops  # local import: kernels are optional
 
-        x_q = ops.quantize_activation(x, scale_format=scale_format)
-        return ops.fp8_matmul(x_q, w_q, out_dtype=x.dtype)
-    x_q = quantize_activation(x, scale_format=scale_format)
-    return _dot(dequantize(x_q, x.dtype), dequantize(w_q, x.dtype))
+        with scope("act_quant"):
+            x_q = ops.quantize_activation(x, scale_format=scale_format)
+        with scope("matmul"):
+            return ops.fp8_matmul(x_q, w_q, out_dtype=x.dtype)
+    with scope("act_quant"):
+        x_dq = dequantize(quantize_activation(x, scale_format=scale_format),
+                          x.dtype)
+    return _dot(x_dq, _weight(w_q, x.dtype))
 
 
 def linear(x: jax.Array, w, *, precision: Optional[PrecisionConfig] = None,
@@ -78,7 +89,7 @@ def linear(x: jax.Array, w, *, precision: Optional[PrecisionConfig] = None,
     """
     if isinstance(w, QuantizedTensor):
         if not quantized:  # excluded layer got a quantized weight: dequant
-            return _dot(x, dequantize(w, x.dtype))
+            return _dot(x, _weight(w, x.dtype))
         fmt = precision.scale_format if precision else ScaleFormat.FP32
         return fp8_linear_rollout(x, w_q=w, scale_format=fmt)
     if precision is not None and precision.fp8_training and quantized:

@@ -114,9 +114,10 @@ def main(argv=None):
                          "keep running, their tokens carry the version "
                          "live at each decode step)")
     ap.add_argument("--trace-out", default=None, metavar="PATH",
-                    help="write a Chrome trace-event JSON of the run "
-                         "(load in Perfetto / chrome://tracing; enables "
-                         "the step tracer)")
+                    help="write a Chrome trace-event JSON of the run, "
+                         "its steps on the host wall clock (load in "
+                         "Perfetto / chrome://tracing; enables the step "
+                         "tracer)")
     ap.add_argument("--events-out", default=None, metavar="PATH",
                     help="write the raw typed event log as JSONL (one "
                          "event per line; enables the step tracer)")
@@ -259,8 +260,8 @@ def main(argv=None):
         if args.trace_out:
             rows = []
             for t in tracers:
-                rows.extend(chrome_trace(
-                    t.events, replica=t.replica)["traceEvents"])
+                rows.extend(chrome_trace(t.events, replica=t.replica,
+                                         clock="wall")["traceEvents"])
             with open(args.trace_out, "w") as f:
                 json.dump({"traceEvents": rows}, f)
 

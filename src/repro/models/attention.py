@@ -27,6 +27,7 @@ from repro.core.quant import (
     quantize_per_tensor,
 )
 from repro.models.common import apply_rope, constrain, dense_init, rms_norm
+from repro.obs.scopes import scope
 
 _NEG_INF = -1e30
 
@@ -99,6 +100,7 @@ def init_paged_kv_cache(num_blocks: int, block_size: int, n_kv_heads: int,
     )
 
 
+@scope("kv_read")
 def _paged_physical(cache: PagedKVCache, block_tables: jax.Array) -> jax.Array:
     """Map logical table entries to physical pool rows (-1 -> trash)."""
     trash = cache.k.shape[0] - 1
@@ -117,6 +119,7 @@ def _live_blocks(context_lengths, w: int, bs: int) -> int:
     return max(1, min(w, -(-m // bs)))
 
 
+@scope("kv_write")
 def paged_write(cache: PagedKVCache, block_tables: jax.Array,
                 positions: jax.Array, valid: jax.Array,
                 kq: jax.Array, vq: jax.Array) -> PagedKVCache:
@@ -139,6 +142,7 @@ def paged_write(cache: PagedKVCache, block_tables: jax.Array,
     )
 
 
+@scope("kv_write")
 def paged_copy_rows(cache: PagedKVCache, src, dst) -> PagedKVCache:
     """Copy physical pool rows `src` -> `dst` — the device half of
     copy-on-write: duplicate a shared block's K/V into a writer's private
@@ -181,8 +185,9 @@ def _project_qkv(x, params, cfg, precision, kv_src=None):
     k = linear(src, params["wk"], precision=precision).reshape(b, sk, kvh, dh)
     v = linear(src, params["wv"], precision=precision).reshape(b, sk, kvh, dh)
     if cfg.qk_norm and "q_norm_scale" in params:
-        q = rms_norm(q, params["q_norm_scale"], cfg.norm_eps)
-        k = rms_norm(k, params["k_norm_scale"], cfg.norm_eps)
+        with scope("attention"):
+            q = rms_norm(q, params["q_norm_scale"], cfg.norm_eps)
+            k = rms_norm(k, params["k_norm_scale"], cfg.norm_eps)
     # head-parallel (or seq-parallel fallback) so the O(S^2) score tensor
     # shards over the model axis — see ShardingRules.activation("act_qkv");
     # K/V sharding must stay compatible with q's (act_kv rule)
@@ -190,6 +195,31 @@ def _project_qkv(x, params, cfg, precision, kv_src=None):
     k = constrain(k, "act_kv", n_heads=cfg.n_heads)
     v = constrain(v, "act_kv", n_heads=cfg.n_heads)
     return q, k, v
+
+
+@scope("attention")
+def _rope_qk(q, k, positions, theta):
+    return apply_rope(q, positions, theta), apply_rope(k, positions, theta)
+
+
+@scope("kv_read")
+def _dequantize_kv(k, v, cache, dtype):
+    """K/V as read from `cache`, in the model's dtype."""
+    if not cache.quantized:
+        return k, v
+    return (dequantize_per_tensor(k, cache.k_scale, dtype),
+            dequantize_per_tensor(v, cache.v_scale, dtype))
+
+
+@scope("kv_read")
+def _gather_pages(cache: PagedKVCache, phys: jax.Array, dtype):
+    """The K/V of physical pages `phys` (B, W) in logical order,
+    (B, W*BS, KVH, D), dequantized to `dtype`."""
+    b, w = phys.shape
+    bs, kvh, dh = cache.k.shape[1:]
+    k = cache.k[phys].reshape(b, w * bs, kvh, dh)
+    v = cache.v[phys].reshape(b, w * bs, kvh, dh)
+    return _dequantize_kv(k, v, cache, dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -222,6 +252,7 @@ def _impl() -> str:
     return getattr(_IMPL_CTX, "impl", "naive")
 
 
+@scope("attention")
 def _sdpa_chunked(q, k, v, precision, cfg, *, prefix_len: int = 0,
                   lengths: Optional[jax.Array] = None, kv_chunk: int = 1024):
     """Online-softmax attention over KV chunks (causal [+ prefix / lengths]).
@@ -284,6 +315,7 @@ def _sdpa_chunked(q, k, v, precision, cfg, *, prefix_len: int = 0,
     return out.astype(q.dtype)
 
 
+@scope("attention")
 def _sdpa(q, k, v, mask, precision: Optional[PrecisionConfig], cfg):
     """q (B,S,H,D), k/v (B,S',KVH,D) bf16; mask broadcast (B,1,S,S') or None."""
     b, s, h, dh = q.shape
@@ -343,8 +375,7 @@ def attention_forward(
     if use_rope and kv_src is None:
         if positions is None:
             positions = jnp.arange(s)[None, :]
-        q = apply_rope(q, positions, cfg.rope_theta)
-        k = apply_rope(k, positions, cfg.rope_theta)
+        q, k = _rope_qk(q, k, positions, cfg.rope_theta)
     if _impl() == "chunked" and causal and kv_src is None:
         out = _sdpa_chunked(q, k, v, precision, cfg,
                             prefix_len=prefix_len, lengths=lengths)
@@ -360,6 +391,7 @@ def attention_forward(
 # Rollout path: prefill + decode against the (possibly fp8) cache
 # ---------------------------------------------------------------------------
 
+@scope("kv_write")
 def _quantize_kv(k, v, cache: KVCache, precision: PrecisionConfig,
                  recalibrate: bool):
     """Quantize fresh K/V for cache insertion.
@@ -404,8 +436,7 @@ def attention_prefill(
     if positions is None:
         positions = jnp.arange(s)[None, :]
     if use_rope:
-        q = apply_rope(q, positions, cfg.rope_theta)
-        k = apply_rope(k, positions, cfg.rope_theta)
+        q, k = _rope_qk(q, k, positions, cfg.rope_theta)
 
     kq, vq, cache = _quantize_kv(k, v, cache, precision, recalibrate=True)
     if isinstance(cache, PagedKVCache):
@@ -415,19 +446,17 @@ def attention_prefill(
             else pos < lengths[:, None]
         cache = paged_write(cache, block_tables, pos, valid, kq, vq)
     else:
-        cache = cache._replace(
-            k=jax.lax.dynamic_update_slice(cache.k, kq, (0, 0, 0, 0)),
-            v=jax.lax.dynamic_update_slice(cache.v, vq, (0, 0, 0, 0)),
-        )
+        with scope("kv_write"):
+            cache = cache._replace(
+                k=jax.lax.dynamic_update_slice(cache.k, kq, (0, 0, 0, 0)),
+                v=jax.lax.dynamic_update_slice(cache.v, vq, (0, 0, 0, 0)),
+            )
 
     # The model consumes what the cache holds: dequantize the quantized K/V
     # so prefill logits match decode-time numerics (train-inference mismatch
     # is then *only* due to quantization, as in the paper).
-    if cache.quantized:
-        k_use = dequantize_per_tensor(kq, cache.k_scale, x.dtype)
-        v_use = dequantize_per_tensor(vq, cache.v_scale, x.dtype)
-    else:
-        k_use, v_use = k, v
+    k_use, v_use = _dequantize_kv(kq, vq, cache, x.dtype) \
+        if cache.quantized else (k, v)
     if _impl() == "chunked":
         out = _sdpa_chunked(q, k_use, v_use, precision, cfg, lengths=lengths)
     else:
@@ -472,8 +501,7 @@ def attention_prefill_chunk(
     q, k, v = _project_qkv(x, params, cfg, precision)
     positions = start[:, None] + jnp.arange(c)[None, :]         # (B, C)
     if use_rope:
-        q = apply_rope(q, positions, cfg.rope_theta)
-        k = apply_rope(k, positions, cfg.rope_theta)
+        q, k = _rope_qk(q, k, positions, cfg.rope_theta)
 
     kq, vq, cache = _quantize_kv(k, v, cache, precision, recalibrate=True)
     valid = positions < lengths[:, None]
@@ -488,19 +516,14 @@ def attention_prefill_chunk(
     if use_kernel:
         from repro.kernels import ops
         g = cfg.n_heads // kvh
-        out = ops.fp8_paged_prefill_attention(
-            q.reshape(b, c, kvh, g, dh),
-            cache.k, cache.v, cache.k_scale, cache.v_scale,
-            phys, start, lengths,
-        ).reshape(b, c, cfg.n_heads * dh).astype(x.dtype)
+        with scope("kv_read"):
+            out = ops.fp8_paged_prefill_attention(
+                q.reshape(b, c, kvh, g, dh),
+                cache.k, cache.v, cache.k_scale, cache.v_scale,
+                phys, start, lengths,
+            ).reshape(b, c, cfg.n_heads * dh).astype(x.dtype)
     else:
-        k_raw = cache.k[phys].reshape(b, w_live * bs, kvh, dh)
-        v_raw = cache.v[phys].reshape(b, w_live * bs, kvh, dh)
-        if cache.quantized:
-            k_all = dequantize_per_tensor(k_raw, cache.k_scale, x.dtype)
-            v_all = dequantize_per_tensor(v_raw, cache.v_scale, x.dtype)
-        else:
-            k_all, v_all = k_raw, v_raw
+        k_all, v_all = _gather_pages(cache, phys, x.dtype)
         k_pos = jnp.arange(w_live * bs)[None, None, :]          # (1, 1, S')
         mask = jnp.logical_and(k_pos <= positions[:, :, None],
                                k_pos < lengths[:, None, None])  # (B, C, S')
@@ -525,44 +548,45 @@ def attention_decode(
     h, kvh, dh = cfg.n_heads, cfg.n_kv_heads, cfg.d_head
     q, k, v = _project_qkv(x, params, cfg, precision)
     if use_rope:
-        pos = lengths[:, None]
-        q = apply_rope(q, pos, cfg.rope_theta)
-        k = apply_rope(k, pos, cfg.rope_theta)
+        q, k = _rope_qk(q, k, lengths[:, None], cfg.rope_theta)
 
     kq, vq, cache = _quantize_kv(k, v, cache, precision, recalibrate=False)
+    with scope("kv_write"):
+        new_lengths = lengths + 1
     if isinstance(cache, PagedKVCache):
         assert block_tables is not None, "paged decode needs block_tables"
         cache = paged_write(cache, block_tables, lengths[:, None],
                             jnp.ones((b, 1), bool), kq, vq)
         return _paged_attention_over_table(
-            x, q, cache, block_tables, lengths + 1, params, precision, cfg,
+            x, q, cache, block_tables, new_lengths, params, precision, cfg,
             use_kernel=use_kernel)
     batch_idx = jnp.arange(b)
-    cache = cache._replace(
-        k=cache.k.at[batch_idx, lengths].set(kq[:, 0]),
-        v=cache.v.at[batch_idx, lengths].set(vq[:, 0]),
-    )
-    new_lengths = lengths + 1
+    with scope("kv_write"):
+        cache = cache._replace(
+            k=cache.k.at[batch_idx, lengths].set(kq[:, 0]),
+            v=cache.v.at[batch_idx, lengths].set(vq[:, 0]),
+        )
 
     if use_kernel:
         from repro.kernels import ops
         g = h // kvh
-        out = ops.fp8_decode_attention(
-            q.reshape(b, kvh, g, dh),
-            cache.k, cache.v, cache.k_scale, cache.v_scale, new_lengths,
-        ).reshape(b, 1, h * dh).astype(x.dtype)
+        with scope("kv_read"):
+            out = ops.fp8_decode_attention(
+                q.reshape(b, kvh, g, dh),
+                cache.k, cache.v, cache.k_scale, cache.v_scale, new_lengths,
+            ).reshape(b, 1, h * dh).astype(x.dtype)
     else:
         # reshard the *fp8 payload* (not the dequantized copy) when the
         # attention math needs the cache replicated over tp — 1 byte/elem on
         # the wire instead of 2-4 (§Perf decode iteration)
-        k_raw = constrain(cache.k, "kv_gather")
-        v_raw = constrain(cache.v, "kv_gather")
-        k_all = dequantize_per_tensor(k_raw, cache.k_scale, x.dtype) \
-            if cache.quantized else k_raw
-        v_all = dequantize_per_tensor(v_raw, cache.v_scale, x.dtype) \
-            if cache.quantized else v_raw
+        with scope("kv_read"):
+            k_raw = constrain(cache.k, "kv_gather")
+            v_raw = constrain(cache.v, "kv_gather")
+        k_all, v_all = _dequantize_kv(k_raw, v_raw, cache, x.dtype)
         s_max = cache.k.shape[1]
-        mask = (jnp.arange(s_max)[None] < new_lengths[:, None])[:, None, :]
+        with scope("attention"):
+            mask = (jnp.arange(s_max)[None] <
+                    new_lengths[:, None])[:, None, :]
         out = _sdpa(q, k_all, v_all, mask, precision, cfg)
     return linear(out, params["wo"], precision=precision), cache
 
@@ -598,20 +622,17 @@ def _paged_attention_over_table(
     if use_kernel:
         from repro.kernels import ops
         g = h // kvh
-        out = ops.fp8_paged_decode_attention(
-            q.reshape(b, kvh, g, dh),
-            cache.k, cache.v, cache.k_scale, cache.v_scale, phys,
-            new_lengths,
-        ).reshape(b, 1, h * dh).astype(x.dtype)
+        with scope("kv_read"):
+            out = ops.fp8_paged_decode_attention(
+                q.reshape(b, kvh, g, dh),
+                cache.k, cache.v, cache.k_scale, cache.v_scale, phys,
+                new_lengths,
+            ).reshape(b, 1, h * dh).astype(x.dtype)
     else:
-        k_raw = cache.k[phys].reshape(b, w_live * bs, kvh, dh)
-        v_raw = cache.v[phys].reshape(b, w_live * bs, kvh, dh)
-        k_all = dequantize_per_tensor(k_raw, cache.k_scale, x.dtype) \
-            if cache.quantized else k_raw
-        v_all = dequantize_per_tensor(v_raw, cache.v_scale, x.dtype) \
-            if cache.quantized else v_raw
-        mask = (jnp.arange(w_live * bs)[None] <
-                new_lengths[:, None])[:, None, :]
+        k_all, v_all = _gather_pages(cache, phys, x.dtype)
+        with scope("attention"):
+            mask = (jnp.arange(w_live * bs)[None] <
+                    new_lengths[:, None])[:, None, :]
         out = _sdpa(q, k_all, v_all, mask, precision, cfg)
     return linear(out, params["wo"], precision=precision), cache
 
@@ -648,11 +669,9 @@ def cross_attention_decode(x, params, cfg, cross_cache: KVCache,
     b, s, _ = x.shape
     h, kvh, dh = cfg.n_heads, cfg.n_kv_heads, cfg.d_head
     q = linear(x, params["wq"], precision=precision).reshape(b, s, h, dh)
-    k = dequantize_per_tensor(cross_cache.k, cross_cache.k_scale, x.dtype) \
-        if cross_cache.quantized else cross_cache.k
-    v = dequantize_per_tensor(cross_cache.v, cross_cache.v_scale, x.dtype) \
-        if cross_cache.quantized else cross_cache.v
+    k, v = _dequantize_kv(cross_cache.k, cross_cache.v, cross_cache, x.dtype)
     s_src = k.shape[1]
-    mask = (jnp.arange(s_src)[None] < src_lengths[:, None])[:, None, :]
+    with scope("attention"):
+        mask = (jnp.arange(s_src)[None] < src_lengths[:, None])[:, None, :]
     out = _sdpa(q, k, v, mask, precision, cfg)
     return linear(out, params["wo"], precision=precision)

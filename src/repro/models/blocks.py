@@ -19,6 +19,7 @@ from repro.models import mlp as mlp_mod
 from repro.models import moe as moe_mod
 from repro.models import ssm as ssm_mod
 from repro.models.common import KeyGen, constrain, rms_norm
+from repro.obs.scopes import scope
 
 
 @dataclasses.dataclass(frozen=True)
@@ -79,6 +80,17 @@ def init_slot_params(keygen: KeyGen, spec: SlotSpec, cfg, dtype) -> dict:
 # Slot application — full sequence (train / score / encoder / prefill)
 # ---------------------------------------------------------------------------
 
+@scope("residual")
+def _norm(x, p, cfg):
+    """A sublayer's pre-norm."""
+    return rms_norm(x, p["norm_scale"], cfg.norm_eps)
+
+
+@scope("residual")
+def _residual(x, h):
+    return x + h
+
+
 def apply_slot_full(
     x, slot_params, spec: SlotSpec, cfg, precision,
     *,
@@ -101,7 +113,7 @@ def apply_slot_full(
 
     if spec.mixer == "attn":
         p = slot_params["attn"]
-        xn = rms_norm(x, p["norm_scale"], cfg.norm_eps)
+        xn = _norm(x, p, cfg)
         if kv_cache is not None and chunk_start is not None:
             h, new_kv = attn_mod.attention_prefill_chunk(
                 xn, p, cfg, kv_cache, precision, start=chunk_start,
@@ -117,10 +129,10 @@ def apply_slot_full(
                 xn, p, cfg, precision, positions=positions, mask=mask,
                 causal=causal, use_rope=use_rope,
                 prefix_len=prefix_len, lengths=lengths)
-        x = x + h
+        x = _residual(x, h)
     else:
         p = slot_params["ssm"]
-        xn = rms_norm(x, p["norm_scale"], cfg.norm_eps)
+        xn = _norm(x, p, cfg)
         # when the recurrent state is carried out (prefill / chunked
         # prefill), padded positions must be state no-ops: `lengths` is the
         # total valid length, so inside a chunk starting at `chunk_start`
@@ -132,11 +144,11 @@ def apply_slot_full(
         h, new_ssm = ssm_mod.ssm_forward(
             xn, p, cfg, precision, state=ssm_state,
             return_state=want_ssm_state, lengths=ssm_lengths)
-        x = x + h
+        x = _residual(x, h)
 
     if spec.cross and enc_out is not None or (spec.cross and cross_cache is not None):
         p = slot_params["cross"]
-        xn = rms_norm(x, p["norm_scale"], cfg.norm_eps)
+        xn = _norm(x, p, cfg)
         if cross_cache is None:
             # training path: direct cross attention over encoder output
             src_mask = None
@@ -149,18 +161,18 @@ def apply_slot_full(
         else:
             h = attn_mod.cross_attention_decode(
                 xn, p, cfg, cross_cache, src_lengths, precision)
-        x = x + h
+        x = _residual(x, h)
 
     if spec.ffn == "mlp":
         p = slot_params["mlp"]
-        xn = rms_norm(x, p["norm_scale"], cfg.norm_eps)
-        x = x + mlp_mod.mlp_forward(xn, p, cfg, precision)
+        xn = _norm(x, p, cfg)
+        x = _residual(x, mlp_mod.mlp_forward(xn, p, cfg, precision))
     elif spec.ffn == "moe":
         p = slot_params["moe"]
-        xn = rms_norm(x, p["norm_scale"], cfg.norm_eps)
+        xn = _norm(x, p, cfg)
         h, moe_aux = moe_mod.moe_forward(
             xn, p, cfg, precision, forced_topk_idx=forced_topk)
-        x = x + h
+        x = _residual(x, h)
         aux.update(moe_aux)
     x = constrain(x, "act_btd")
     return x, aux, new_kv, new_ssm
@@ -185,32 +197,32 @@ def apply_slot_decode(
 
     if spec.mixer == "attn":
         p = slot_params["attn"]
-        xn = rms_norm(x, p["norm_scale"], cfg.norm_eps)
+        xn = _norm(x, p, cfg)
         h, new_kv = attn_mod.attention_decode(
             xn, p, cfg, kv_cache, lengths, precision,
             block_tables=block_tables, use_kernel=use_kernel)
-        x = x + h
+        x = _residual(x, h)
     else:
         p = slot_params["ssm"]
-        xn = rms_norm(x, p["norm_scale"], cfg.norm_eps)
+        xn = _norm(x, p, cfg)
         h, new_ssm = ssm_mod.ssm_decode(xn, p, cfg, ssm_state, precision)
-        x = x + h
+        x = _residual(x, h)
 
     if spec.cross:
         p = slot_params["cross"]
-        xn = rms_norm(x, p["norm_scale"], cfg.norm_eps)
-        x = x + attn_mod.cross_attention_decode(
-            xn, p, cfg, cross_cache, src_lengths, precision)
+        xn = _norm(x, p, cfg)
+        x = _residual(x, attn_mod.cross_attention_decode(
+            xn, p, cfg, cross_cache, src_lengths, precision))
 
     if spec.ffn == "mlp":
         p = slot_params["mlp"]
-        xn = rms_norm(x, p["norm_scale"], cfg.norm_eps)
-        x = x + mlp_mod.mlp_forward(xn, p, cfg, precision)
+        xn = _norm(x, p, cfg)
+        x = _residual(x, mlp_mod.mlp_forward(xn, p, cfg, precision))
     elif spec.ffn == "moe":
         p = slot_params["moe"]
-        xn = rms_norm(x, p["norm_scale"], cfg.norm_eps)
+        xn = _norm(x, p, cfg)
         h, moe_aux = moe_mod.moe_forward(
             xn, p, cfg, precision, forced_topk_idx=forced_topk)
-        x = x + h
+        x = _residual(x, h)
         aux.update(moe_aux)
     return x, aux, new_kv, new_ssm

@@ -9,6 +9,7 @@ import jax.numpy as jnp
 from repro.core.fp8_linear import linear
 from repro.core.precision import PrecisionConfig
 from repro.models.common import constrain, dense_init
+from repro.obs.scopes import scope
 
 _ACT = {
     "silu": jax.nn.silu,
@@ -29,6 +30,7 @@ def init_mlp_params(keygen, cfg, dtype=jnp.bfloat16) -> dict:
     return p
 
 
+@scope("mlp")
 def mlp_forward(x: jax.Array, params: dict, cfg,
                 precision: Optional[PrecisionConfig] = None) -> jax.Array:
     act = _ACT[cfg.act]

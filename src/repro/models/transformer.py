@@ -27,6 +27,7 @@ from repro.models import attention as attn_mod
 from repro.models import blocks as blocks_mod
 from repro.models import ssm as ssm_mod
 from repro.models.common import KeyGen, constrain, embed_init, dense_init, rms_norm
+from repro.obs.scopes import scope
 
 BF16 = jnp.bfloat16
 
@@ -102,10 +103,12 @@ def init_params(cfg, key, dtype=BF16) -> dict:
 # shared pieces
 # ---------------------------------------------------------------------------
 
+@scope("embed")
 def _embed(params, tokens):
     return jnp.take(params["emb"], tokens, axis=0)
 
 
+@scope("head")
 def _unembed(params, x, cfg, precision):
     x = rms_norm(x, params["final_norm_scale"], cfg.norm_eps)
     head = params["emb"].T if cfg.tie_embeddings else params["lm_head"]
@@ -552,7 +555,9 @@ def decode_step(
         return h, ys
 
     x, ys = _scan(body, x, (params["blocks"], cache["slots"]))
-    cache = dict(cache, slots=ys["caches"], lengths=lengths + 1)
+    with scope("kv_write"):
+        lengths = lengths + 1
+    cache = dict(cache, slots=ys["caches"], lengths=lengths)
     logits = _unembed(params, x[:, 0], cfg, precision)
     aux = {"routing": ys["routing"]} if want_routing else {}
     return logits, cache, aux

@@ -6,6 +6,8 @@ Layering (no engine imports here — `obs` depends only on `roofline`):
 - `obs.tracer`   — `NULL_TRACER` default + recording `StepTracer`
 - `obs.timeline` — per-request TTFT/TPOT/queue-wait/preemption post-pass
 - `obs.export`   — JSONL sink + Chrome trace-event (Perfetto) exporter
+- `obs.scopes`   — the names the program gives its work on the profiler's
+  clock: device scopes in the compiled HLO, program host spans
 
 The engine owns one tracer (`NULL_TRACER` unless a `StepTracer` is
 passed), every instrumentation site costs one branch when disabled, and
@@ -35,8 +37,14 @@ from repro.obs.export import (  # noqa: F401
     JsonlSink,
     chrome_trace,
     read_events_jsonl,
-    read_metrics_jsonl,
     write_events_jsonl,
+)
+from repro.obs.scopes import (  # noqa: F401
+    DEVICE_SCOPES,
+    PHASES,
+    PROGRAM_SPANS,
+    scope,
+    span,
 )
 from repro.obs.timeline import (  # noqa: F401
     RequestTimeline,

@@ -217,7 +217,9 @@ class WeightsEvent(Event):
 class StepEvent(Event):
     """End-of-step accounting: the executed decision's token costs and
     the clock. `clock` is the END-of-step clock (clock_before +
-    cost_tokens) — the arrival time of every token the step emitted."""
+    cost_tokens) — the arrival time of every token the step emitted.
+    `wall_start_ns` / `wall_end_ns` bound the step on the host's
+    `time.perf_counter_ns()` clock (None in logs that predate them)."""
 
     clock_before: float
     cost_tokens: int
@@ -226,6 +228,8 @@ class StepEvent(Event):
     decode_tokens: int
     swap_tokens: int
     version: int
+    wall_start_ns: Optional[int] = None
+    wall_end_ns: Optional[int] = None
 
     kind = "step"
 

@@ -11,7 +11,8 @@ Two output shapes, one event schema:
 
 - **Chrome trace-event** (`chrome_trace`): the Perfetto-loadable
   ``{"traceEvents": [...]}`` format.  The token-unit clock maps to
-  microseconds (`ts`/`dur`); pid = replica, tid = slot.  Work items
+  microseconds (`ts`/`dur`), or with ``clock="wall"`` the steps' host
+  wall-clock bounds do; pid = replica, tid = slot.  Work items
   (prefill / verify / decode) are ``"X"`` complete events spanning their
   step, lifecycle markers (submit / admit / swap / weights / finish) are
   ``"i"`` instants, and pool gauges are ``"C"`` counter tracks.
@@ -89,12 +90,6 @@ def read_events_jsonl(path: str) -> List[ev.Event]:
     return out
 
 
-def read_metrics_jsonl(path: str) -> List[dict]:
-    """Load a plain metrics JSONL stream (trainer sink) as dicts."""
-    with open(path) as f:
-        return [json.loads(line) for line in f if line.strip()]
-
-
 def _span(name: str, cat: str, pid: int, tid: int, ts: float, dur: float,
           args: dict) -> dict:
     return {"name": name, "cat": cat, "ph": "X", "pid": pid, "tid": tid,
@@ -107,23 +102,48 @@ def _instant(name: str, cat: str, pid: int, tid: int, ts: float,
             "tid": tid, "ts": float(ts), "args": args}
 
 
-def chrome_trace(events: List[ev.Event], replica: int = 0) -> dict:
+def chrome_trace(events: List[ev.Event], replica: int = 0,
+                 clock: str = "token") -> dict:
     """Render an event stream as Chrome trace-event JSON.
 
-    One token-clock unit = 1 us.  Work spans cover their whole step (the
-    fused trace retires at once); per-kind args carry the token/byte
-    accounting so Perfetto's slice pane shows the decision numbers.
+    `clock="token"` (the default): one token-clock unit = 1 us.
+    `clock="wall"`: each step's work sits on the host wall clock its
+    `StepEvent` recorded (`time.perf_counter`, in us), so the replicas of
+    one process share a timeline; an instant
+    or gauge that carries only a token clock sits at the start (gauges:
+    the end) of its step.  Where a step event carries no wall bounds the
+    whole export stays on the token clock.  Work spans cover their whole
+    step (the fused trace retires at once); per-kind args carry the
+    token/byte accounting so Perfetto's slice pane shows the decision
+    numbers.
     """
-    step_start = {e.step: e.clock_before for e in events
-                  if isinstance(e, ev.StepEvent)}
-    step_dur = {e.step: e.cost_tokens for e in events
-                if isinstance(e, ev.StepEvent)}
+    assert clock in ("token", "wall"), clock
+    steps = [e for e in events if isinstance(e, ev.StepEvent)]
+    wall = clock == "wall" and bool(steps) and all(
+        e.wall_start_ns is not None and e.wall_end_ns is not None
+        for e in steps)
+    if wall:
+        step_start = {e.step: e.wall_start_ns / 1e3 for e in steps}
+        step_dur = {e.step: (e.wall_end_ns - e.wall_start_ns) / 1e3
+                    for e in steps}
+    else:
+        step_start = {e.step: e.clock_before for e in steps}
+        step_dur = {e.step: e.cost_tokens for e in steps}
+    last_end = max((step_start[s] + step_dur[s] for s in step_start),
+                   default=0.0)
 
     def ts(step: int) -> float:
-        return step_start.get(step, float(step))
+        return step_start.get(step, last_end if wall else float(step))
 
     def dur(step: int) -> float:
         return step_dur.get(step, 1.0)
+
+    def at(e, end: bool = False) -> float:
+        """An event with its own token `clock`, on the export's clock."""
+        if not wall:
+            return e.clock
+        return ts(e.step) + (dur(e.step) if end and e.step in step_dur
+                             else 0.0)
 
     pid = replica
     rows: List[dict] = [
@@ -156,7 +176,7 @@ def chrome_trace(events: List[ev.Event], replica: int = 0) -> dict:
                     {"rid": rid, "context": ctx, "version": e.version}))
         elif isinstance(e, ev.SubmitEvent):
             rows.append(_instant(f"submit r{e.rid}", "lifecycle", pid, 0,
-                                 e.clock, {"rid": e.rid,
+                                 at(e), {"rid": e.rid,
                                            "prompt_len": e.prompt_len}))
         elif isinstance(e, ev.AdmitEvent):
             name = "swap_in" if e.swap_in else "admit"
@@ -177,63 +197,64 @@ def chrome_trace(events: List[ev.Event], replica: int = 0) -> dict:
         elif isinstance(e, ev.WeightsEvent):
             rows.append(_instant(
                 f"weights v{e.version}" + (" staged" if e.staged else ""),
-                "weights", pid, 0, e.clock,
+                "weights", pid, 0, at(e),
                 {"version": e.version, "staged": e.staged}))
         elif isinstance(e, ev.ReplicaDownEvent):
             rows.append(_instant(
                 f"replica_down r{e.replica} ({e.reason})", "fault",
-                e.replica, 0, e.clock,
+                e.replica, 0, at(e),
                 {"replica": e.replica, "transient": e.transient,
                  "reason": e.reason}))
         elif isinstance(e, ev.ReplicaUpEvent):
             rows.append(_instant(
                 f"replica_up r{e.replica} v{e.version}", "fault",
-                e.replica, 0, e.clock,
+                e.replica, 0, at(e),
                 {"replica": e.replica, "version": e.version}))
         elif isinstance(e, ev.RedispatchEvent):
             rows.append(_instant(
                 f"redispatch r{e.rid} {e.src_replica}->{e.dst_replica}",
-                "fault", e.dst_replica, 0, e.clock,
+                "fault", e.dst_replica, 0, at(e),
                 {"rid": e.rid, "src": e.src_replica, "dst": e.dst_replica,
                  "replayed_tokens": e.replayed_tokens}))
         elif isinstance(e, ev.PushRetryEvent):
             rows.append(_instant(
                 f"push_retry r{e.replica} v{e.version} #{e.attempt}",
-                "fault", e.replica, 0, e.clock,
+                "fault", e.replica, 0, at(e),
                 {"replica": e.replica, "version": e.version,
                  "attempt": e.attempt}))
         elif isinstance(e, ev.QuarantineEvent):
             rows.append(_instant(
                 f"quarantine r{e.replica} v{e.version}", "fault",
-                e.replica, 0, e.clock,
+                e.replica, 0, at(e),
                 {"replica": e.replica, "version": e.version}))
         elif isinstance(e, ev.AbortEvent):
             rows.append(_instant(
                 f"abort r{e.rid} ({e.reason})", "fault", e.replica, 0,
-                e.clock,
+                at(e),
                 {"rid": e.rid, "reason": e.reason,
                  "n_tokens": e.n_tokens}))
         elif isinstance(e, ev.FleetGaugeEvent):
             rows.append({"name": "fleet health", "ph": "C", "pid": pid,
-                         "ts": float(e.clock),
+                         "ts": float(at(e, end=True)),
                          "args": {"healthy": e.healthy_replicas,
                                   "quarantined": e.quarantined}})
             rows.append({"name": "failover", "ph": "C", "pid": pid,
-                         "ts": float(e.clock),
+                         "ts": float(at(e, end=True)),
                          "args": {"redispatches": e.redispatches,
                                   "replayed_tokens": e.replayed_tokens,
                                   "aborted": e.aborted}})
         elif isinstance(e, ev.GaugeEvent):
             rows.append({"name": "kv blocks", "ph": "C", "pid": pid,
-                         "ts": float(e.clock),
+                         "ts": float(at(e, end=True)),
                          "args": {"in_use": e.blocks_in_use,
                                   "free": e.blocks_free,
                                   "cached": e.blocks_cached,
                                   "state": e.state_block_equiv}})
             rows.append({"name": "pressure", "ph": "C", "pid": pid,
-                         "ts": float(e.clock),
+                         "ts": float(at(e, end=True)),
                          "args": {"kv_pressure": e.kv_pressure,
                                   "queue": e.queue_len}})
     return {"traceEvents": rows,
             "displayTimeUnit": "ms",
-            "otherData": {"clock": "token-units (1 unit = 1us)"}}
+            "otherData": {"clock": "host wall clock (perf_counter us)"
+                          if wall else "token-units (1 unit = 1us)"}}

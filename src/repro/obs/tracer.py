@@ -12,9 +12,12 @@ decision/engine state and append typed `obs.events` records.
 executed decision's `cost_tokens`), so traces from manually-driven
 benchmarks (scheduler.step -> engine.execute loops) and `engine.run()`
 agree — the clock is a property of *executed work*, not of any driver.
+Beside it each `StepEvent` carries the step's host wall-clock bounds
+(`time.perf_counter_ns`), read only while a `StepTracer` is installed.
 """
 from __future__ import annotations
 
+import time
 from typing import List, Optional
 
 from repro.obs import events as ev
@@ -65,6 +68,7 @@ class StepTracer:
         self.step = 0                 # index of the step being executed
         self._geo: Optional[KVGeometry] = None
         self._staged_since: Optional[float] = None
+        self._wall_start: Optional[int] = None
 
     # -- plumbing ----------------------------------------------------------
 
@@ -83,6 +87,7 @@ class StepTracer:
 
     def begin_step(self, eng) -> None:
         self.geometry(eng)
+        self._wall_start = time.perf_counter_ns()
 
     def end_step(self, eng, decision) -> None:
         """Close the step: accounting record + gauges, advance clock."""
@@ -95,6 +100,8 @@ class StepTracer:
             decode_tokens=len(decision.decode_slots),
             swap_tokens=decision.swap_tokens,
             version=eng.weight_version,
+            wall_start_ns=self._wall_start,
+            wall_end_ns=time.perf_counter_ns(),
         ))
         self.clock += decision.cost_tokens
         self.record_gauges(eng)

@@ -30,6 +30,7 @@ from repro.data import tasks
 from repro.models import decode_step, init_cache, prefill
 from repro.models import attention as attn_mod
 from repro.models import blocks as blocks_mod
+from repro.obs.scopes import scope
 
 
 class Trajectory(NamedTuple):
@@ -43,6 +44,7 @@ class Trajectory(NamedTuple):
     response_lengths: jax.Array  # (B,)
     routing: Optional[dict]      # RRR: prefill/decode expert choices
     kv_scales: Optional[dict]    # per-slot (R,) k/v scales after calibration
+    decode_passes: Optional[jax.Array] = None  # () int32 decode loop passes
 
 
 @dataclasses.dataclass(frozen=True)
@@ -111,36 +113,39 @@ def generate(
     # group == 1: identity block tables (sequence i owns a contiguous run).
     # group > 1 : prompt i's first `fp` blocks are physically shared by its
     #             G samples; the rest are per-sample private rows.
-    if group == 1:
-        cache = init_cache(cfg, b, max_len, precision, src_len=src_len,
-                           page_size=page_size)
-    else:
-        fp, priv, w = _group_layout(p, g, page_size, shared_prefix_blocks)
-        cache = init_cache(cfg, b, max_len, precision, src_len=src_len,
-                           page_size=page_size,
-                           num_pages=b * fp + n * priv)
-        cache["block_tables"] = _prefill_tables(b, group, w, fp, priv)
-    if kv_scales is not None:
-        from repro.rl.calibration import apply_kv_scales
-        cache = apply_kv_scales(cache, kv_scales)
-    out = prefill(rollout_params, inputs, cache, cfg, precision,
-                  want_routing=want_routing)
-    if want_routing:
-        logits0, cache, prefill_routing = out
-    else:
-        logits0, cache = out
-        prefill_routing = None
+    with scope("prefill"):
+        if group == 1:
+            cache = init_cache(cfg, b, max_len, precision, src_len=src_len,
+                               page_size=page_size)
+        else:
+            fp, priv, w = _group_layout(p, g, page_size, shared_prefix_blocks)
+            cache = init_cache(cfg, b, max_len, precision, src_len=src_len,
+                               page_size=page_size,
+                               num_pages=b * fp + n * priv)
+            cache["block_tables"] = _prefill_tables(b, group, w, fp, priv)
+        if kv_scales is not None:
+            from repro.rl.calibration import apply_kv_scales
+            cache = apply_kv_scales(cache, kv_scales)
+        out = prefill(rollout_params, inputs, cache, cfg, precision,
+                      want_routing=want_routing)
+        if want_routing:
+            logits0, cache, prefill_routing = out
+        else:
+            logits0, cache = out
+            prefill_routing = None
 
     if group > 1:
-        # fork: CoW the boundary blocks, share the rest, tile logits and
-        # per-sequence state so every sample decodes independently
-        cache = _fork_group(cache, b, group, p, page_size, fp, priv, w)
-        logits0 = jnp.repeat(logits0, group, axis=0)
-        prompts = jnp.repeat(prompts, group, axis=0)
-        prompt_lengths = jnp.repeat(prompt_lengths, group, axis=0)
+        with scope("fork"):
+            # fork: CoW the boundary blocks, share the rest, tile logits and
+            # per-sequence state so every sample decodes independently
+            cache = _fork_group(cache, b, group, p, page_size, fp, priv, w)
+            logits0 = jnp.repeat(logits0, group, axis=0)
+            prompts = jnp.repeat(prompts, group, axis=0)
+            prompt_lengths = jnp.repeat(prompt_lengths, group, axis=0)
 
-    key, k0 = jax.random.split(key)
-    tok0, logp0 = _sample(logits0, k0, sampler.temperature, sampler.top_k)
+    with scope("sample"):
+        key, k0 = jax.random.split(key)
+        tok0, logp0 = _sample(logits0, k0, sampler.temperature, sampler.top_k)
 
     pattern = blocks_mod.layer_pattern(cfg)
     moe_slots = [f"s{j}" for j, s in enumerate(pattern) if s.ffn == "moe"]
@@ -168,6 +173,7 @@ def generate(
     def cond(s):
         return jnp.logical_and(s["i"] < g, ~jnp.all(s["done"]))
 
+    @scope("decode")
     def body(s):
         i = s["i"]
         # Ordering invariant: the token sampled in the previous iteration is
@@ -176,22 +182,26 @@ def generate(
         # sequence commits PAD/0 from here on.  The decode step below runs
         # unconditionally (fixed shapes) — its writes for done rows are
         # masked out by `response_mask` downstream.
-        resp = s["resp"].at[:, i].set(
-            jnp.where(s["done"], sampler.pad_id, s["tok"]))
-        logps = s["logps"].at[:, i].set(jnp.where(s["done"], 0.0, s["logp"]))
-        mask = s["mask"].at[:, i].set(jnp.where(s["done"], 0.0, 1.0))
-        done = s["done"] | (s["tok"] == sampler.eos_id)
+        with scope("commit"):
+            resp = s["resp"].at[:, i].set(
+                jnp.where(s["done"], sampler.pad_id, s["tok"]))
+            logps = s["logps"].at[:, i].set(
+                jnp.where(s["done"], 0.0, s["logp"]))
+            mask = s["mask"].at[:, i].set(jnp.where(s["done"], 0.0, 1.0))
+            done = s["done"] | (s["tok"] == sampler.eos_id)
+            passes = i + 1
 
         logits, cache, aux = decode_step(
             rollout_params, s["tok"], s["cache"], cfg, precision,
             want_routing=want_routing)
-        key, kk = jax.random.split(s["key"])
-        tok, logp = _sample(logits, kk, sampler.temperature, sampler.top_k)
+        with scope("sample"):
+            key, kk = jax.random.split(s["key"])
+            tok, logp = _sample(logits, kk, sampler.temperature, sampler.top_k)
         routing = s["routing"]
         if routing is not None:
             routing = {name: routing[name].at[i].set(aux["routing"][name])
                        for name in routing}
-        return dict(i=i + 1, tok=tok, logp=logp, done=done, key=key,
+        return dict(i=passes, tok=tok, logp=logp, done=done, key=key,
                     cache=cache, resp=resp, logps=logps, mask=mask,
                     routing=routing)
 
@@ -214,6 +224,7 @@ def generate(
         response_lengths=resp_lengths,
         routing=routing,
         kv_scales=kv_scales,
+        decode_passes=state["i"],
     )
 
 
@@ -283,10 +294,11 @@ def _fork_group(cache: dict, b: int, group: int, p: int, page_size: int,
         cache = dict(cache, slots=slots)
 
     # per-sample tables: shared prefix rows + own private run
-    ii = (jnp.arange(n) // group)[:, None]
-    jj = jnp.arange(w)[None, :]
-    own = pool0 + jnp.arange(n)[:, None] * priv + (jj - fp)
-    tables = jnp.where(jj < fp, ii * fp + jj, own).astype(jnp.int32)
+    with scope("kv_write"):
+        ii = (jnp.arange(n) // group)[:, None]
+        jj = jnp.arange(w)[None, :]
+        own = pool0 + jnp.arange(n)[:, None] * priv + (jj - fp)
+        tables = jnp.where(jj < fp, ii * fp + jj, own).astype(jnp.int32)
 
     def tile(a):
         return jnp.repeat(a, group, axis=1) \

@@ -26,6 +26,7 @@ from repro.checkpoint import Checkpointer
 from repro.core.precision import PrecisionConfig
 from repro.data import PromptPipeline
 from repro.models import forward_train, init_params
+from repro.obs.scopes import span
 from repro.optim import AdamWConfig, init as opt_init, update as opt_update
 from repro.rl import calibration as calib_mod
 from repro.rl import rewards as rewards_mod
@@ -242,93 +243,99 @@ class RLTrainer:
         t_start = time.perf_counter()
 
         # 1. prompts (over-provisioned groups double as straggler headroom)
-        batch = self.pipeline.next_batch()
-        problems = [p for p in batch.problems for _ in range(rl.n_per_prompt)]
+        with span("rl.prompts"):
+            batch = self.pipeline.next_batch()
+            problems = [p for p in batch.problems for _ in range(rl.n_per_prompt)]
 
         # 2. weight sync (paper Fig 1 phase 2).  The fleet backend pushes a
         # version-stamped snapshot and hot-swaps it into every replica at a
         # step boundary — in-flight requests (none here, but the same code
         # path serves the async case) are not drained
-        rollout_precision = self._rollout_precision()
-        token_versions = None
-        if rl.rollout_backend == "fleet":
-            if self._fleet is None:
-                vw = self.syncer.push(self.params)
-                self._fleet = self._build_fleet(vw.params, vw.version)
+        with span("rl.sync"):
+            rollout_precision = self._rollout_precision()
+            token_versions = None
+            if rl.rollout_backend == "fleet":
+                if self._fleet is None:
+                    vw = self.syncer.push(self.params)
+                    self._fleet = self._build_fleet(vw.params, vw.version)
+                else:
+                    # failure-aware push: the version is minted only after
+                    # the fleet accepts the install (bounded retry inside),
+                    # so a failed sync never desyncs trainer vs fleet
+                    vw = self.syncer.push_to(self.params, self._fleet)
+                sync_stats = vw.stats
             else:
-                # failure-aware push: the version is minted only after
-                # the fleet accepts the install (bounded retry inside),
-                # so a failed sync never desyncs trainer vs fleet
-                vw = self.syncer.push_to(self.params, self._fleet)
-            sync_stats = vw.stats
-        else:
-            rollout_params, sync_stats = sync_policy_weights(
-                self.params, rollout_precision)
+                rollout_params, sync_stats = sync_policy_weights(
+                    self.params, rollout_precision)
 
         # 3. rollout on the FP8 engine — GRPO group sampling prefills each
         # prompt once and forks per-sample block tables, so the group's
         # prompt KV is stored once instead of n_per_prompt times; the
         # shared-prefix width follows the shortest prompt in the batch
         # (static arg: recompiles at most once per distinct value)
-        self.key, k_gen = jax.random.split(self.key)
-        t_roll = time.perf_counter()
-        if rl.rollout_backend == "fleet":
-            traj, token_versions = self._fleet_rollout(batch)
-        else:
-            page_size = 8
-            traj = generate(
-                rollout_params, jnp.asarray(batch.tokens),
-                jnp.asarray(batch.lengths), k_gen,
-                cfg, rollout_precision, self.sampler,
-                want_routing=rl.precision.rollout_router_replay,
-                kv_scales=self.kv_scales,
-                page_size=page_size,
-                num_samples_per_prompt=rl.n_per_prompt,
-                shared_prefix_blocks=int(np.min(batch.lengths)) // page_size,
-            )
-            jax.block_until_ready(traj)
-            del rollout_params        # free the fp8 copy before the update
-        rollout_s = time.perf_counter() - t_roll
-        gen_tokens = float(traj.response_mask.sum())
+        with span("rl.rollout"):
+            self.key, k_gen = jax.random.split(self.key)
+            t_roll = time.perf_counter()
+            if rl.rollout_backend == "fleet":
+                traj, token_versions = self._fleet_rollout(batch)
+            else:
+                page_size = 8
+                traj = generate(
+                    rollout_params, jnp.asarray(batch.tokens),
+                    jnp.asarray(batch.lengths), k_gen,
+                    cfg, rollout_precision, self.sampler,
+                    want_routing=rl.precision.rollout_router_replay,
+                    kv_scales=self.kv_scales,
+                    page_size=page_size,
+                    num_samples_per_prompt=rl.n_per_prompt,
+                    shared_prefix_blocks=int(np.min(batch.lengths)) // page_size,
+                )
+                jax.block_until_ready(traj)
+                del rollout_params        # free the fp8 copy before the update
+            rollout_s = time.perf_counter() - t_roll
+            gen_tokens = float(traj.response_mask.sum())
 
         # 4. rewards + advantages
-        resp = np.asarray(traj.response_tokens)
-        rlen = np.asarray(traj.response_lengths)
-        rewards = rewards_mod.batch_rewards(problems, resp, rlen)
-        if rl.overlong_shaping:
-            rewards = rewards + np.asarray(
-                overlong_penalty(traj.response_lengths, rl.max_new_tokens))
-        adv = group_advantages(jnp.asarray(rewards), rl.n_per_prompt)
-        mask = traj.response_mask
-        if rl.dynamic_sampling:
-            ds = dynamic_sampling_mask(jnp.asarray(rewards), rl.n_per_prompt)
-            mask = mask * ds[:, None]
+        with span("rl.rewards"):
+            resp = np.asarray(traj.response_tokens)
+            rlen = np.asarray(traj.response_lengths)
+            rewards = rewards_mod.batch_rewards(problems, resp, rlen)
+            if rl.overlong_shaping:
+                rewards = rewards + np.asarray(
+                    overlong_penalty(traj.response_lengths, rl.max_new_tokens))
+            adv = group_advantages(jnp.asarray(rewards), rl.n_per_prompt)
+            mask = traj.response_mask
+            if rl.dynamic_sampling:
+                ds = dynamic_sampling_mask(jnp.asarray(rewards), rl.n_per_prompt)
+                mask = mask * ds[:, None]
 
         # 5. update
-        update_batch = {
-            "packed_tokens": packed_sequences(traj),
-            "prompt_lengths": traj.prompt_lengths,
-            "rollout_logps": traj.rollout_logps,
-            "advantages": adv,
-            "mask": mask,
-            "response_mask": traj.response_mask,
-        }
-        if token_versions is not None:
-            update_batch["token_versions"] = token_versions
-        t_upd = time.perf_counter()
-        self.params, self.opt_state, stats = jax.block_until_ready(
-            self.update_fn(self.params, self.opt_state, update_batch))
-        update_s = time.perf_counter() - t_upd
+        with span("rl.update"):
+            update_batch = {
+                "packed_tokens": packed_sequences(traj),
+                "prompt_lengths": traj.prompt_lengths,
+                "rollout_logps": traj.rollout_logps,
+                "advantages": adv,
+                "mask": mask,
+                "response_mask": traj.response_mask,
+            }
+            if token_versions is not None:
+                update_batch["token_versions"] = token_versions
+            t_upd = time.perf_counter()
+            self.params, self.opt_state, stats = jax.block_until_ready(
+                self.update_fn(self.params, self.opt_state, update_batch))
+            update_s = time.perf_counter() - t_upd
 
         # 6. trainer-side calibration for the *next* rollout (paper §B.2)
-        if rl.calibration == "trainer" and not cfg.attention_free:
-            calib = {
-                "tokens": update_batch["packed_tokens"][: rl.prompt_batch],
-                "lengths": (traj.prompt_lengths
-                            + traj.response_lengths)[: rl.prompt_batch],
-            }
-            self.kv_scales = calib_mod.calibrate_kv_scales(
-                self.params, calib, cfg)
+        with span("rl.calibrate"):
+            if rl.calibration == "trainer" and not cfg.attention_free:
+                calib = {
+                    "tokens": update_batch["packed_tokens"][: rl.prompt_batch],
+                    "lengths": (traj.prompt_lengths
+                                + traj.response_lengths)[: rl.prompt_batch],
+                }
+                self.kv_scales = calib_mod.calibrate_kv_scales(
+                    self.params, calib, cfg)
 
         self.step_idx += 1
         # scalars -> float; per-version stat vectors (mismatch_kl_per_
@@ -355,8 +362,9 @@ class RLTrainer:
             self.metrics_sink.write(metrics)
 
         # 7. checkpoint
-        if self.ckpt and self.step_idx % rl.ckpt_every == 0:
-            self.save_checkpoint()
+        with span("rl.checkpoint"):
+            if self.ckpt and self.step_idx % rl.ckpt_every == 0:
+                self.save_checkpoint()
         return metrics
 
     # ------------------------------------------------------------------

@@ -27,10 +27,14 @@ import jax
 from repro.core.fp8_params import count_quantized, quantize_params
 from repro.core.precision import PrecisionConfig
 from repro.core.quant import QuantizedTensor, quantization_rel_error
+from repro.obs.scopes import scope, span
 
 
-# one jit for every sync: traced once per (param shapes, precision)
-_quantize_params = jax.jit(quantize_params, static_argnums=1)
+# one jit for every sync: traced once per (param shapes, precision); the
+# scope keeps `quantize_params`'s name, so the executable stays
+# jit_quantize_params
+_quantize_params = jax.jit(scope("quantize")(quantize_params),
+                           static_argnums=1)
 
 
 def sync_policy_weights(
@@ -40,18 +44,20 @@ def sync_policy_weights(
     rollout_shardings=None,
 ) -> Tuple[object, dict]:
     """BF16 train params -> rollout params.  Returns (params, stats)."""
-    t0 = time.perf_counter()
-    if not precision.any_fp8_rollout and \
-            precision.router_dtype.value == "bf16":
-        return train_params, {"sync_ms": 0.0, "quantized_leaves": 0}
+    with span("weight_sync"):
+        t0 = time.perf_counter()
+        if not precision.any_fp8_rollout and \
+                precision.router_dtype.value == "bf16":
+            return train_params, {"sync_ms": 0.0, "quantized_leaves": 0}
 
-    rollout_params = _quantize_params(train_params, precision)
-    if rollout_shardings is not None:
-        rollout_params = jax.device_put(rollout_params, rollout_shardings)
-    jax.block_until_ready(rollout_params)
-    stats = dict(count_quantized(rollout_params))
-    stats["sync_ms"] = (time.perf_counter() - t0) * 1e3
-    return rollout_params, stats
+        rollout_params = _quantize_params(train_params, precision)
+        if rollout_shardings is not None:
+            rollout_params = jax.device_put(rollout_params,
+                                            rollout_shardings)
+        jax.block_until_ready(rollout_params)
+        stats = dict(count_quantized(rollout_params))
+        stats["sync_ms"] = (time.perf_counter() - t0) * 1e3
+        return rollout_params, stats
 
 
 @dataclasses.dataclass(frozen=True)

@@ -124,6 +124,7 @@ from repro.models import blocks as blocks_mod
 from repro.models import ssm as ssm_mod
 from repro.models import decode_step, init_cache, prefill, prefill_chunk
 from repro.models.attention import paged_copy_rows
+from repro.obs.scopes import span
 from repro.obs.tracer import NULL_TRACER
 from repro.serving.block_manager import BlockManager
 from repro.serving.faults import NULL_INJECTOR
@@ -803,16 +804,17 @@ class ServingEngine:
         scheduler's bookkeeping already assumed it: a victim's rows are
         copied to host before any later-ordered action can overwrite
         them); the fused decode over `decode_slots` runs last."""
-        tracing = self.tracer.enabled
-        if tracing:
-            self.tracer.begin_step(self)
-        self._executing = True
-        try:
-            self._execute(decision)
-        finally:
-            self._executing = False
-        if tracing:
-            self.tracer.end_step(self, decision)
+        with span("engine.step"):
+            tracing = self.tracer.enabled
+            if tracing:
+                self.tracer.begin_step(self)
+            self._executing = True
+            try:
+                self._execute(decision)
+            finally:
+                self._executing = False
+            if tracing:
+                self.tracer.end_step(self, decision)
 
     def _execute(self, decision: ScheduleDecision):
         tracing = self.tracer.enabled

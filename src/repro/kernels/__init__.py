@@ -3,6 +3,8 @@
 fp8_gemm          — blockwise-scaled FP8 GEMM (DeepGEMM analogue)
 fp8_quant         — fused blockwise quantization (weight-sync / activations)
 fp8_kv_attention  — FlashDecoding over an fp8 KV cache
+fp8_qdq_attention — full-FP8 decode attention's matmuls over a gathered
+                    fp8 K/V view, its QDQ done in VMEM
 
 `ops` is the public API (backend dispatch + padding); `ref` holds the
 pure-jnp oracles the kernels are validated against.

@@ -18,6 +18,7 @@ from repro.core.precision import E4M3, ScaleFormat
 from repro.core.quant import QuantizedTensor
 from repro.kernels import fp8_gemm as _gemm
 from repro.kernels import fp8_kv_attention as _attn
+from repro.kernels import fp8_qdq_attention as _qdq_attn
 from repro.kernels import fp8_quant as _quant
 
 
@@ -145,3 +146,27 @@ def fp8_decode_attention(q, k_cache, v_cache, k_scale, v_scale, lengths,
     return _attn.fp8_decode_attention(
         q, k_cache, v_cache, k_scale, v_scale, lengths, bs=bs,
         interpret=_interpret())
+
+
+def qdq_attention_kernels_apply(q: jax.Array, kv: jax.Array) -> bool:
+    """Whether full-FP8 attention of q (B, S, H, D) over the fp8 view kv
+    (B, T, KVH, D) runs in `qdq_scores`/`qdq_pv`: compiled on a TPU, for
+    one bf16 query token and heads of one 1x128 tile, in a process with one
+    device.  The kernels have no partitioning rule, so under a mesh XLA
+    would gather their operands to every device; there the caller keeps
+    its XLA path."""
+    return (not _interpret() and jax.device_count() == 1
+            and q.shape[1] == 1 and q.dtype == jnp.bfloat16
+            and kv.shape[-1] <= 128)
+
+
+def qdq_scores(q, k, k_scale):
+    """Scores of q (B, KVH, G, D) over the full-FP8 QDQ of the fp8 K view
+    (B, T, KVH, D) dequantized with `k_scale`: (B, KVH, G, T)."""
+    return _qdq_attn.qdq_scores(q, k, k_scale, interpret=_interpret())
+
+
+def qdq_pv(p, v, v_scale):
+    """p (B, KVH, G, T) times the full-FP8 QDQ of the fp8 V view
+    (B, T, KVH, D) dequantized with `v_scale`: (B, KVH, G, D)."""
+    return _qdq_attn.qdq_pv(p, v, v_scale, interpret=_interpret())

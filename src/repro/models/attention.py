@@ -212,14 +212,14 @@ def _dequantize_kv(k, v, cache, dtype):
 
 
 @scope("kv_read")
-def _gather_pages(cache: PagedKVCache, phys: jax.Array, dtype):
+def _gather_pages(cache: PagedKVCache, phys: jax.Array):
     """The K/V of physical pages `phys` (B, W) in logical order,
-    (B, W*BS, KVH, D), dequantized to `dtype`."""
+    (B, W*BS, KVH, D), as the pool stores them."""
     b, w = phys.shape
     bs, kvh, dh = cache.k.shape[1:]
     k = cache.k[phys].reshape(b, w * bs, kvh, dh)
     v = cache.v[phys].reshape(b, w * bs, kvh, dh)
-    return _dequantize_kv(k, v, cache, dtype)
+    return k, v
 
 
 # ---------------------------------------------------------------------------
@@ -315,6 +315,18 @@ def _sdpa_chunked(q, k, v, precision, cfg, *, prefix_len: int = 0,
     return out.astype(q.dtype)
 
 
+def _probs(scores, mask, precision: Optional[PrecisionConfig], dh: int):
+    """Attention probabilities from raw `scores` (mask broadcast against
+    them, or None); E4M3 QDQ'd under full-FP8 attention."""
+    scores = scores.astype(jnp.float32) * (dh ** -0.5)
+    if mask is not None:
+        scores = jnp.where(mask, scores, _NEG_INF)
+    p = jax.nn.softmax(scores, axis=-1)
+    if precision is not None and precision.quantize_attention:
+        p = qdq(p.astype(jnp.bfloat16)).astype(jnp.float32)
+    return p
+
+
 @scope("attention")
 def _sdpa(q, k, v, mask, precision: Optional[PrecisionConfig], cfg):
     """q (B,S,H,D), k/v (B,S',KVH,D) bf16; mask broadcast (B,1,S,S') or None."""
@@ -330,25 +342,50 @@ def _sdpa(q, k, v, mask, precision: Optional[PrecisionConfig], cfg):
         v = jnp.repeat(v, g, axis=2)
         k = constrain(k, "act_qkv")
         v = constrain(v, "act_qkv")
-        scores = jnp.einsum("bshd,bthd->bhst", q, k).astype(jnp.float32)
-        scores = scores * (dh ** -0.5)
-        if mask is not None:
-            scores = jnp.where(mask[:, None], scores, _NEG_INF)
-        p = jax.nn.softmax(scores, axis=-1)
-        if precision is not None and precision.quantize_attention:
-            p = qdq(p.astype(jnp.bfloat16)).astype(jnp.float32)
+        scores = jnp.einsum("bshd,bthd->bhst", q, k)
+        p = _probs(scores, None if mask is None else mask[:, None],
+                   precision, dh)
         out = jnp.einsum("bhst,bthd->bshd", p.astype(v.dtype), v)
         return out.reshape(b, s, h * dh)
     qg = q.reshape(b, s, kvh, g, dh)
-    scores = jnp.einsum("bskgd,btkd->bkgst", qg, k).astype(jnp.float32)
-    scores = scores * (dh ** -0.5)
-    if mask is not None:
-        scores = jnp.where(mask[:, None, None], scores, _NEG_INF)
-    p = jax.nn.softmax(scores, axis=-1)
-    if precision is not None and precision.quantize_attention:
-        p = qdq(p.astype(jnp.bfloat16)).astype(jnp.float32)
+    scores = jnp.einsum("bskgd,btkd->bkgst", qg, k)
+    p = _probs(scores, None if mask is None else mask[:, None, None],
+               precision, dh)
     out = jnp.einsum("bkgst,btkd->bskgd", p.astype(v.dtype), v)
     return out.reshape(b, s, h * dh)
+
+
+@scope("attention")
+def _sdpa_qdq_kernels(q, k, v, cache, mask, precision: PrecisionConfig):
+    """`_sdpa` of one query token over fp8 payloads k/v (B,T,KVH,D) under
+    full-FP8 attention, the two matmuls in Pallas kernels that QDQ K and V
+    in VMEM; mask (B,1,T)."""
+    from repro.kernels import ops
+    b, _, h, dh = q.shape
+    kvh = k.shape[2]
+    qg = qdq(q).reshape(b, kvh, h // kvh, dh)
+    scores = ops.qdq_scores(qg, k, cache.k_scale)                # (B,KVH,G,T)
+    p = _probs(scores, mask[:, None], precision, dh)
+    out = ops.qdq_pv(p.astype(q.dtype), v, cache.v_scale)       # (B,KVH,G,D)
+    return out.reshape(b, 1, h * dh)
+
+
+def _sdpa_over_cache(q, k, v, cache, mask, precision: PrecisionConfig, cfg,
+                     dtype):
+    """`_sdpa` over K/V `k`/`v` as `cache` stores them.
+
+    Full-FP8 attention over an fp8 cache QDQs K/V that are an fp8 payload
+    times a per-layer scale.  Where `kernels.ops` runs the QDQ kernels
+    (compiled, one query token), that QDQ is done from the payload in VMEM
+    beside the two matmuls, so no f32 or second fp8 copy of the view is
+    written to HBM; everywhere else the view is dequantized for `_sdpa`.
+    """
+    from repro.kernels import ops
+    if (cache.quantized and precision.quantize_attention
+            and _impl() == "naive" and ops.qdq_attention_kernels_apply(q, k)):
+        return _sdpa_qdq_kernels(q, k, v, cache, mask, precision)
+    k, v = _dequantize_kv(k, v, cache, dtype)
+    return _sdpa(q, k, v, mask, precision, cfg)
 
 
 def causal_mask(s: int, dtype=bool) -> jax.Array:
@@ -523,7 +560,8 @@ def attention_prefill_chunk(
                 phys, start, lengths,
             ).reshape(b, c, cfg.n_heads * dh).astype(x.dtype)
     else:
-        k_all, v_all = _gather_pages(cache, phys, x.dtype)
+        k_all, v_all = _dequantize_kv(*_gather_pages(cache, phys), cache,
+                                      x.dtype)
         k_pos = jnp.arange(w_live * bs)[None, None, :]          # (1, 1, S')
         mask = jnp.logical_and(k_pos <= positions[:, :, None],
                                k_pos < lengths[:, None, None])  # (B, C, S')
@@ -543,7 +581,12 @@ def attention_decode(
     use_kernel: bool = False,
     block_tables: Optional[jax.Array] = None,   # (B, W) paged cache only
 ):
-    """One decode step: append K/V, attend over [0:lengths]+self."""
+    """One decode step: append K/V, attend over [0:lengths]+self.
+
+    `use_kernel` takes the FlashDecoding kernels, which skip full-FP8
+    attention's Q/K/V/P QDQ.  Without it the step computes `_sdpa`'s math,
+    whose K/V QDQ over an fp8 cache `kernels.ops` may run in its QDQ
+    kernels (`_sdpa_over_cache`)."""
     b = x.shape[0]
     h, kvh, dh = cfg.n_heads, cfg.n_kv_heads, cfg.d_head
     q, k, v = _project_qkv(x, params, cfg, precision)
@@ -582,12 +625,12 @@ def attention_decode(
         with scope("kv_read"):
             k_raw = constrain(cache.k, "kv_gather")
             v_raw = constrain(cache.v, "kv_gather")
-        k_all, v_all = _dequantize_kv(k_raw, v_raw, cache, x.dtype)
         s_max = cache.k.shape[1]
         with scope("attention"):
             mask = (jnp.arange(s_max)[None] <
                     new_lengths[:, None])[:, None, :]
-        out = _sdpa(q, k_all, v_all, mask, precision, cfg)
+        out = _sdpa_over_cache(q, k_raw, v_raw, cache, mask, precision, cfg,
+                               x.dtype)
     return linear(out, params["wo"], precision=precision), cache
 
 
@@ -629,11 +672,12 @@ def _paged_attention_over_table(
                 new_lengths,
             ).reshape(b, 1, h * dh).astype(x.dtype)
     else:
-        k_all, v_all = _gather_pages(cache, phys, x.dtype)
+        k_all, v_all = _gather_pages(cache, phys)
         with scope("attention"):
             mask = (jnp.arange(w_live * bs)[None] <
                     new_lengths[:, None])[:, None, :]
-        out = _sdpa(q, k_all, v_all, mask, precision, cfg)
+        out = _sdpa_over_cache(q, k_all, v_all, cache, mask, precision, cfg,
+                               x.dtype)
     return linear(out, params["wo"], precision=precision), cache
 
 
@@ -669,9 +713,9 @@ def cross_attention_decode(x, params, cfg, cross_cache: KVCache,
     b, s, _ = x.shape
     h, kvh, dh = cfg.n_heads, cfg.n_kv_heads, cfg.d_head
     q = linear(x, params["wq"], precision=precision).reshape(b, s, h, dh)
-    k, v = _dequantize_kv(cross_cache.k, cross_cache.v, cross_cache, x.dtype)
-    s_src = k.shape[1]
+    s_src = cross_cache.k.shape[1]
     with scope("attention"):
         mask = (jnp.arange(s_src)[None] < src_lengths[:, None])[:, None, :]
-    out = _sdpa(q, k, v, mask, precision, cfg)
+    out = _sdpa_over_cache(q, cross_cache.k, cross_cache.v, cross_cache, mask,
+                           precision, cfg, x.dtype)
     return linear(out, params["wo"], precision=precision)

@@ -20,12 +20,14 @@ from jax.sharding import SingleDeviceSharding
 from repro.configs import get_config
 from repro.kernels import fp8_gemm, fp8_quant
 from repro.kernels import fp8_kv_attention as attn
+from repro.kernels import fp8_qdq_attention as qdq_attn
 
 CFG = get_config("qwen3-8b")
 KVH, D = CFG.n_kv_heads, CFG.d_head
 G = CFG.n_heads // CFG.n_kv_heads
 K, N = CFG.d_model, CFG.d_ff
 M, B, PAGES, PAGE, TABLE, CHUNK = 256, 8, 64, 16, 8, 16
+ROLLOUT_B, ROLLOUT_T = 512, 392   # the rollout's gathered view: 49 pages of 8
 F8 = jnp.float8_e4m3fn
 
 
@@ -80,6 +82,14 @@ KERNELS = {
         [((B, KVH, G, D), jnp.bfloat16), ((B, 1024, KVH, D), F8),
          ((B, 1024, KVH, D), F8), ((), jnp.float32), ((), jnp.float32),
          ((B,), jnp.int32)]),
+    "qdq_scores": (
+        lambda *a: qdq_attn.qdq_scores(*a, interpret=False),
+        [((ROLLOUT_B, KVH, G, D), jnp.bfloat16),
+         ((ROLLOUT_B, ROLLOUT_T, KVH, D), F8), ((), jnp.float32)]),
+    "qdq_pv": (
+        lambda *a: qdq_attn.qdq_pv(*a, interpret=False),
+        [((ROLLOUT_B, KVH, G, ROLLOUT_T), jnp.bfloat16),
+         ((ROLLOUT_B, ROLLOUT_T, KVH, D), F8), ((), jnp.float32)]),
 }
 
 
